@@ -56,6 +56,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.cep import engine as eng
 from repro.cep import patterns as pat
 from repro.cep import runner
@@ -422,6 +423,7 @@ def main(argv=None) -> None:
                     help="fail if events/s regresses >20% vs this JSON")
     ap.add_argument("--out", default="BENCH_engine.json")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     # Quick mode shrinks ONLY the event counts/repeats — identical
     # configurations, so per-event rates stay comparable with the

@@ -26,6 +26,7 @@ import json
 import pathlib
 import sys
 
+from repro import compile_cache
 from repro.eval import sweep
 
 
@@ -39,6 +40,7 @@ def main(argv=None) -> int:
     ap.add_argument("--results-dir", default=None,
                     help="also write per-dataset quality_<ds>.json here")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     bench = sweep.run_quality_sweep(quick=args.quick,
                                     results_dir=args.results_dir)

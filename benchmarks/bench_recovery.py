@@ -2,8 +2,7 @@
 (DESIGN.md §13).
 
 Every cell of (backend × shedder) runs the seeded supervisor workload
-twice: once uninterrupted in-process (the reference), once under the
-chaos harness — a subprocess SIGKILLed at a seeded kill site (mid-chunk,
+twice: once uninterrupted (the reference), once under the chaos harness — a subprocess SIGKILLed at a seeded kill site (mid-chunk,
 mid-refresh, or mid-snapshot-write, cycled across the grid), then
 relaunched to recover from the newest valid snapshot + WAL tail and
 finish the stream.  The gates are absolute:
@@ -17,9 +16,13 @@ finish the stream.  The gates are absolute:
                      torn file that recovery CRC-rejected in favor of
                      the previous generation.
 
-A snapshot-cadence sweep (in-process crash simulation: abandon the
-runtime mid-stream, recover in a fresh one) reports recovery wall time
-vs WAL replay length as the cadence coarsens — the knob's cost curve.
+A snapshot-cadence sweep (SIGKILL at a fixed chunk, recover in a fresh
+process) reports recovery wall time vs WAL replay length as the cadence
+coarsens — the knob's cost curve.
+
+Every run happens in a child process (``Supervisor``); this process
+never initializes a JAX backend, so on a TPU host the children get the
+device.  The platform recorded is the one the children report.
 
 Writes BENCH_recovery.json (always, also on failure) and exits 1 on any
 gate failure; CI runs ``--quick`` and gates merges on it.
@@ -34,8 +37,7 @@ import tempfile
 import time
 import traceback
 
-import jax
-
+from repro import compile_cache
 from repro.cep import engine as eng
 from repro import runtime as RT
 from repro.runtime import supervisor as SV
@@ -104,41 +106,27 @@ def run_cell(backend: str, shedder: str, site: str, spec: dict,
 
 
 def cadence_sweep(spec: dict, everies: tuple[int, ...],
-                  crash_after_pushes: int = 3) -> list[dict]:
-    """In-process crash simulation per snapshot cadence: run
-    ``crash_after_pushes`` pushes, abandon the runtime (its disk state is
-    exactly what a SIGKILL leaves), recover in a fresh runtime, finish,
-    and compare against the uninterrupted run.  Coarser cadences replay
-    more WAL records; the rows quantify that recovery-time cost."""
-    ref = SV.run_service(spec, persist_dir=None)
+                  crash_chunk: int = 6) -> list[dict]:
+    """Crash per snapshot cadence: SIGKILL the child at chunk
+    ``crash_chunk``, recover in a fresh child, finish, and compare
+    against the uninterrupted run.  Coarser cadences replay more WAL
+    records; the rows quantify that recovery-time cost."""
+    with tempfile.TemporaryDirectory() as d:
+        ref = SV.Supervisor(d).reference(spec)
     rows = []
     for every in everies:
         s = dict(spec, snapshot_every=every)
         row: dict = {"cell": f"cadence_{every}", "snapshot_every": every}
         try:
             with tempfile.TemporaryDirectory() as d:
-                specs, cfg, model, ev = SV.build_workload(s)
-                a = SV.MatchRuntime(cfg, model, SV.runtime_config(s, d),
-                                    specs=specs)
-                n = RT.num_events(ev)
-                push = s["push"]
-                for st in range(0, crash_after_pushes * push, push):
-                    a.push(RT.slice_events(ev, st, min(st + push, n)))
-                a.persist.wal.close()
-                del a
-                b = SV.MatchRuntime(cfg, model, SV.runtime_config(s, d),
-                                    specs=specs)
-                rec = b.recover_from_disk()
-                for st in range(b.persist.wal.next_record_id * push, n,
-                                push):
-                    b.push(RT.slice_events(ev, st, min(st + push, n)))
-                b.flush()
+                res = SV.Supervisor(d).run(s, kill=f"chunk:{crash_chunk}")
+            rep, rec = res["report"], res["report"]["recovery"]
             row.update(replayed_records=rec["replayed_records"],
                        recovery_wall_s=rec["recovery_wall_s"],
                        snapshot_chunk=rec["snapshot_chunk"])
-            row["ok_bitwise"] = (
-                SV.carry_sha(b) == ref["carry_sha"]
-                and SV.semantic_counters(b) == ref["counters"])
+            row["ok_recovered"] = res["recovered"]
+            row["ok_bitwise"] = (rep["carry_sha"] == ref["carry_sha"]
+                                 and rep["counters"] == ref["counters"])
         except Exception:
             row["ok_no_exception"] = False
             row["traceback"] = traceback.format_exc()
@@ -155,10 +143,11 @@ def main(argv=None) -> None:
     ap.add_argument("--quick", action="store_true", help="CI-sized run")
     ap.add_argument("--out", default="BENCH_recovery.json")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     n, push, chunk = (1536, 256, 128) if args.quick else (3072, 256, 128)
 
-    out = {"quick": bool(args.quick), "backend": jax.default_backend(),
+    out = {"quick": bool(args.quick), "backend": None,
            "n_events": n, "chunk_size": chunk, "cells": [],
            "cadence_sweep": []}
     t_all = time.time()
@@ -171,7 +160,9 @@ def main(argv=None) -> None:
             site = sites[i % len(sites)]
             spec = make_spec(backend, shedder, n, push, chunk)
             try:
-                ref = SV.run_service(spec, persist_dir=None)
+                with tempfile.TemporaryDirectory() as d:
+                    ref = SV.Supervisor(d).reference(spec)
+                out["backend"] = ref["platform"]
             except Exception:
                 out["cells"].append({
                     "cell": f"{backend}/{shedder}", "kill_site": site,

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.cep import engine as eng
 from repro.cep import patterns as pat
 from repro.cep import runner
@@ -181,6 +182,7 @@ def main(argv=None) -> None:
                     help="CI-sized run")
     ap.add_argument("--out", default="BENCH_runtime.json")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     # max_pms=64 on both tiers: multi-tenant consolidation is the
     # many-SMALL-tenants regime — lane-batching amortizes per-op overhead

@@ -11,6 +11,7 @@ import sys
 import time
 
 from benchmarks import figures
+from repro import compile_cache
 
 
 def _emit(name: str, us_per_call: float, derived: str) -> None:
@@ -25,6 +26,7 @@ def main(argv=None) -> None:
                     help="comma-separated figure names")
     ap.add_argument("--out", default="results/paper_figures.json")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     figs = {
         "fig5": figures.fig5_match_probability,

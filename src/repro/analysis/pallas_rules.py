@@ -33,9 +33,10 @@ from repro.analysis import rules as R
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 
 # The block_step megakernel aliases these store buffers in place
-# (kernels/block_step.py): active, state, open_idx, bind, idset, ring,
-# ring_ptr, complex_count, pms_created, lat_n, lat_l.
-BLOCK_STEP_MIN_ALIASES = 11
+# (kernels/block_step.py): active, state, open_idx, bind, ring, ring_ptr,
+# complex_count, pms_created, lat_n, lat_l (plus idset for ANY patterns
+# and the observation matrices while gathering stats).
+BLOCK_STEP_MIN_ALIASES = 10
 
 _BANNED_INNER = ("sort", "pure_callback", "io_callback", "debug_callback")
 
@@ -56,13 +57,19 @@ def pallas_calls(jaxpr) -> list:
 
 
 def _kernel_name(eqn) -> str:
-    info = eqn.params.get("name_and_src_info")
-    return getattr(info, "name", None) or str(info or "pallas_call")
+    return eqn.params.get("name") or "pallas_call"
+
+
+def _block_dims(bm) -> tuple:
+    """Block sizes per dim: ``Blocked``/``Element`` carry a size, a
+    ``Squeezed`` dim is one element."""
+    return tuple(int(getattr(d, "block_size", 1) if not isinstance(d, int)
+                     else d) for d in bm.block_shape)
 
 
 def _block_bytes(bm) -> int:
-    shape = tuple(int(d) for d in bm.block_shape)
-    dt = bm.array_shape_dtype.dtype
+    shape = _block_dims(bm)
+    dt = bm.array_aval.dtype
     return math.prod(shape) * dt.itemsize if shape else dt.itemsize
 
 
@@ -113,8 +120,8 @@ def check_pallas_calls(art: R.Artifact, ctr) -> list:
         # -- tile-multiple + grid-bounds per operand ---------------------
         bad_tile, bad_bounds = [], []
         for k, bm in enumerate(bms):
-            ashape = tuple(int(d) for d in bm.array_shape_dtype.shape)
-            bshape = tuple(int(d) for d in bm.block_shape)
+            ashape = tuple(int(d) for d in bm.array_aval.shape)
+            bshape = _block_dims(bm)
             if len(ashape) != len(bshape):
                 bad_tile.append(f"op{k}: rank {ashape} vs block {bshape}")
                 continue

@@ -666,22 +666,31 @@ def _post_shed(cfg: EngineConfig, model: EngineModel, c: Carry,
                                   live_class, ev_bind, ev_id)
 
     # -- 6. observations (model-building phase only) -------------------------
+    # Exact per-event transition counts (integer-valued f32, so the
+    # scatter-add order cannot matter); rewards add count × the pattern's
+    # per-PM time, elementwise — the same bits on every backend.
     obs_counts, obs_rewards = c.obs_counts, c.obs_rewards
     if cfg.gather_stats:
         P, N, M = cfg.num_patterns, cfg.max_pms, cfg.max_states
         w = pms.active.astype(jnp.float32)                    # observed PMs
-        t = (cfg.c_match * model.proc_cost)[:, None] * w      # per-PM time
         pidx = jnp.arange(P, dtype=jnp.int32)[:, None] * jnp.ones(
             (1, N), jnp.int32)
         flat = (pidx * M + s_old) * M + s_new
-        obs_counts = obs_counts.reshape(-1).at[flat.reshape(-1)].add(
-            w.reshape(-1)).reshape(P, M, M)
-        obs_rewards = obs_rewards.reshape(-1).at[flat.reshape(-1)].add(
-            t.reshape(-1)).reshape(P, M, M)
+        delta = jnp.zeros((P * M * M,), jnp.float32).at[
+            flat.reshape(-1)].add(w.reshape(-1)).reshape(P, M, M)
+        obs_counts = obs_counts + delta
+        obs_rewards = obs_rewards + delta * (
+            cfg.c_match * model.proc_cost)[:, None, None]
 
     # -- 7. simulated processing time & latency ------------------------------
+    # The per-pattern sum runs in pattern order (a reduce may reassociate,
+    # and the block kernel and the oracle add in this order).
     n_active_p = pms.active.sum(axis=1).astype(jnp.float32)  # matched-against
-    t_proc = cfg.c_base + (cfg.c_match * model.proc_cost * n_active_p).sum()
+    terms = cfg.c_match * model.proc_cost * n_active_p
+    t_sum = terms[0]
+    for p in range(1, cfg.num_patterns):
+        t_sum = t_sum + terms[p]
+    t_proc = cfg.c_base + t_sum
     t_proc = jnp.where(ev_dropped, cfg.c_ebl, t_proc)
     sim_time = c.sim_time + t_proc
     l_e = sim_time - arrival

@@ -14,6 +14,7 @@ inverses.
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -41,9 +42,40 @@ jax.tree_util.register_pytree_node(
 )
 
 
+LN2 = math.log(2.0)
+
+
+def log2(x: Array) -> Array:
+    """log2 of a positive normal f32, from its exponent bits and a short
+    atanh series in add, multiply and divide.
+
+    On a TPU, ``jnp.log2`` is rounded differently by XLA and by the
+    kernel compiler (Mosaic); add, multiply and divide are not, so the
+    block kernel's Algorithm-1 arithmetic stays bit-identical to the xla
+    step for NLOGN models too.  Accurate to 2.5 f32 ulps: m in [√½, √2)
+    gives |s| ≤ 0.172, and the first omitted term is below 4e-10.
+    """
+    f32, i32 = jnp.float32, jnp.int32
+    x = jnp.asarray(x, f32)
+    # The kernel compiler bitcasts vectors only: lift a scalar to (1, 1).
+    v = x.reshape(1, 1) if x.ndim == 0 else x
+    bits = jax.lax.bitcast_convert_type(v, i32)
+    e = (bits >> 23) - 127
+    m = jax.lax.bitcast_convert_type((bits & 0x007FFFFF) | 0x3F800000, f32)
+    big = m > 1.4142135
+    m = jnp.where(big, m * 0.5, m)
+    e = jnp.where(big, e + 1, e)
+    s = (m - 1.0) / (m + 1.0)
+    s2 = s * s
+    p = ((((s2 * (1.0 / 9.0) + 1.0 / 7.0) * s2 + 1.0 / 5.0) * s2
+          + 1.0 / 3.0) * s2 + 1.0)
+    out = e.astype(f32) + (2.0 * s * p) * (1.0 / LN2)
+    return out[0, 0] if x.ndim == 0 else out
+
+
 def _basis(n: Array, kind: Array) -> Array:
     n = n.astype(jnp.float32)
-    return jnp.where(kind == LINEAR, n, n * jnp.log2(n + 1.0))
+    return jnp.where(kind == LINEAR, n, n * log2(n + 1.0))
 
 
 def _lstsq_1d(x: Array, y: Array, w: Array) -> tuple[Array, Array]:
@@ -85,22 +117,26 @@ def predict_latency(model: LatencyModel, n_pm: Array) -> Array:
     return model.a * _basis(jnp.asarray(n_pm), model.kind) + model.b
 
 
+def _invert_nlogn(t: Array) -> Array:
+    """Solve n·log2(n+1) = t by 16 Newton steps (monotone, convex — a
+    handful suffice).  A ``fori_loop``: the block kernel inlines this, and
+    the TPU kernel compiler lowers a counted loop but not a scan."""
+    def newton(_, n):
+        fn = n * log2(n + 1.0) - t
+        dfn = log2(n + 1.0) + n / ((n + 1.0) * LN2)
+        return jnp.clip(n - fn / jnp.maximum(dfn, 1e-9), 0.0, 1e12)
+
+    return jax.lax.fori_loop(0, 16, newton, jnp.maximum(t, 1.0))
+
+
 def invert_latency(model: LatencyModel, l_target: Array) -> Array:
     """n'_pm = f^{-1}(l'_p)  (Alg. 1 line 7).
 
-    Linear: n = (l-b)/a.  For n·log2(n+1): Newton iterations (monotone,
-    convex — converges in a handful of steps; fixed 16 for jit).
+    Linear: n = (l-b)/a.  For n·log2(n+1): Newton iterations
+    (``_invert_nlogn``).
     """
     t = jnp.maximum((l_target - model.b) / model.a, 0.0)
-
-    def newton(n, _):
-        fn = n * jnp.log2(n + 1.0) - t
-        dfn = jnp.log2(n + 1.0) + n / ((n + 1.0) * jnp.log(2.0))
-        n = jnp.clip(n - fn / jnp.maximum(dfn, 1e-9), 0.0, 1e12)
-        return n, None
-
-    n_nlogn, _ = jax.lax.scan(newton, jnp.maximum(t, 1.0), None, length=16)
-    return jnp.where(model.kind == LINEAR, t, n_nlogn)
+    return jnp.where(model.kind == LINEAR, t, _invert_nlogn(t))
 
 
 def invert_latency_lazy(model: LatencyModel, l_target: Array) -> Array:
@@ -112,18 +148,7 @@ def invert_latency_lazy(model: LatencyModel, l_target: Array) -> Array:
     — under vmap (tenant lanes) the cond lowers back to a select, which
     is exactly ``invert_latency``'s cost."""
     t = jnp.maximum((l_target - model.b) / model.a, 0.0)
-
-    def newton_path(t):
-        def newton(n, _):
-            fn = n * jnp.log2(n + 1.0) - t
-            dfn = jnp.log2(n + 1.0) + n / ((n + 1.0) * jnp.log(2.0))
-            n = jnp.clip(n - fn / jnp.maximum(dfn, 1e-9), 0.0, 1e12)
-            return n, None
-
-        n, _ = jax.lax.scan(newton, jnp.maximum(t, 1.0), None, length=16)
-        return n
-
-    return jax.lax.cond(model.kind == LINEAR, lambda t: t, newton_path, t)
+    return jax.lax.cond(model.kind == LINEAR, lambda t: t, _invert_nlogn, t)
 
 
 @dataclasses.dataclass

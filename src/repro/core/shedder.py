@@ -143,6 +143,71 @@ def threshold_drop_mask(active: Array, utilities: Array, rho: Array, *,
     return active & ~drop
 
 
+def threshold_drop_mask_rows(active: Array, utilities: Array, rho: Array, *,
+                             nbins: int = 128, levels: int = 3) -> Array:
+    """``threshold_drop_mask`` over a (R, N) store, flat order row-major,
+    written in operations the TPU kernel compiler lowers (no scatter,
+    searchsorted or cumsum) — the block kernel's in-kernel Algorithm 2.
+
+    Bitwise the same drop mask as ``threshold_drop_mask`` on the
+    flattened arrays: each level's cumulative bucket counts are counted
+    directly against the shared edge expression of ``bucket_edges``
+    (``cum[b] = #{u < edges[b+1]}``, since every candidate lies at or
+    above ``edges[0] = lo``), the boundary bucket is ``#{b: cum[b] <
+    need}`` (``searchsorted`` left), and the slot-order remainder takes
+    the ``need`` lowest flat positions by a binary search on the count of
+    candidates below a position instead of a running rank.
+    """
+    f32, i32 = jnp.float32, jnp.int32
+    R, N = active.shape
+    u = utilities.astype(f32)
+    n_active = jnp.sum(active.astype(i32))
+    need = jnp.minimum(rho.astype(i32), n_active)
+    lo = jnp.min(jnp.where(active, u, _BIG))
+    hi0 = jnp.max(jnp.where(active, u, -_BIG))
+    hi = jnp.where(hi0 > lo, hi0, lo + 1.0)
+    b_up = jax.lax.broadcasted_iota(i32, (nbins, 1), 0) + 1   # b + 1
+    mask = active
+    drop = jnp.zeros_like(active)
+
+    def edge_at(lo, hi, k):
+        # ``bucket_edges(lo, hi, nbins)[k]``: the same f32 expression.
+        return lo + (hi - lo) * k.astype(f32) / nbins
+
+    for _ in range(levels):
+        upper_edges = jnp.where(b_up == nbins, jnp.inf,
+                                edge_at(lo, hi, b_up))      # (nbins, 1)
+        cum = jnp.zeros((nbins, 1), i32)
+        for r in range(R):
+            below_b = (u[r:r + 1, :] < upper_edges) & mask[r:r + 1, :]
+            cum = cum + jnp.sum(below_b.astype(i32), axis=1, keepdims=True)
+        kb = jnp.minimum(jnp.sum((cum < need).astype(i32)), nbins - 1)
+        edge = edge_at(lo, hi, kb)
+        upper = jnp.where(kb == nbins - 1, jnp.inf, edge_at(lo, hi, kb + 1))
+        below = mask & (u < edge)
+        drop = drop | below
+        need = jnp.maximum(need - jnp.sum(below.astype(i32)), 0)
+        mask = mask & ~below & (u < upper)
+        lo = edge
+        hi_next = jnp.where(kb == nbins - 1, hi, upper)
+        hi = jnp.where(hi_next > lo, hi_next, lo + 1.0)
+    # Exact-ρ remainder: the `need` lowest flat positions left in `mask`.
+    # Smallest t with #{mask & pos < t} >= need, by bisection on [0, R·N].
+    pos = (jax.lax.broadcasted_iota(i32, (R, N), 0) * N +
+           jax.lax.broadcasted_iota(i32, (R, N), 1))
+
+    def bisect(_, lh):
+        t_lo, t_hi = lh
+        mid = (t_lo + t_hi) // 2
+        ok = jnp.sum((mask & (pos < mid)).astype(i32)) >= need
+        return jnp.where(ok, t_lo, mid + 1), jnp.where(ok, mid, t_hi)
+
+    steps = max(1, (R * N).bit_length())
+    _, t = jax.lax.fori_loop(0, steps, bisect, (jnp.int32(0), jnp.int32(R * N)))
+    drop = drop | (mask & (pos < t))
+    return active & ~drop
+
+
 def random_drop(key: Array, active: Array, rho: Array) -> Array:
     """PM-BL: drop a uniformly random ρ-subset of active PMs — exactly ρ
     dropped, matching the budget the overload detector computed.  Realized

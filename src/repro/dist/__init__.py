@@ -1,10 +1,8 @@
-"""Distribution subsystem: sharding rules + version-compat shims.
+"""Distribution subsystem: sharding rules.
 
 ``repro.dist.sharding`` owns every PartitionSpec the launch entry points
 use (params / optimizer state / batches / decode caches) plus the CEP
-engine's pattern-parallel specs (``pm_specs`` / ``run_engine_sharded``).
-``repro.dist.compat`` bridges jax API drift (shard_map location,
-AbstractMesh constructor, mesh-context activation) so the same call sites
-run on 0.4.x and 0.5+.
+engine's pattern-parallel specs (``pm_specs`` / ``run_engine_sharded``),
+and builds meshes with the Auto axis types those specs need.
 """
-from repro.dist import compat, sharding  # noqa: F401
+from repro.dist import sharding  # noqa: F401

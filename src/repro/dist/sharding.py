@@ -34,14 +34,28 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import (AbstractMesh, AxisType, NamedSharding,
+                          PartitionSpec as P)
 
-from repro.dist import compat
 from repro.models.config import ModelConfig
 
 PyTree = Any
 
-abstract_mesh = compat.abstract_mesh  # version-safe AbstractMesh ctor
+
+# Meshes here use Auto axes: jax meshes default to Explicit axes, under
+# which the specs below and ``with_sharding_constraint`` do not apply.
+
+def make_mesh(shape, axis_names, devices=None):
+    """``jax.make_mesh`` with Auto axes."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names), **kw)
+
+
+def abstract_mesh(axis_sizes, axis_names) -> AbstractMesh:
+    """Device-free mesh with Auto axes, for spec construction."""
+    return AbstractMesh(tuple(int(s) for s in axis_sizes), tuple(axis_names),
+                        axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 # ---------------------------------------------------------------------------
@@ -406,7 +420,7 @@ def run_engine_sharded(cfg, model, events, carry, mesh=None,
 
     if mesh is None:
         ndev = len(jax.devices())
-        mesh = jax.make_mesh((ndev,), (axis,))
+        mesh = make_mesh((ndev,), (axis,))
     specs = pm_specs(mesh, cfg, axis=axis)
     if specs["pattern_axis"] is None:
         return eng.run_engine(cfg, model, events, carry)
@@ -418,13 +432,12 @@ def run_engine_sharded(cfg, model, events, carry, mesh=None,
         new_c, outs = eng.run_engine(local_cfg, model, events, carry)
         return _merge_pattern_shards(new_c, outs, axis)
 
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         local_run, mesh=mesh,
         in_specs=(specs["model"], specs["events"], specs["carry"]),
         out_specs=(specs["carry"], specs["out"]),
-        check_rep=False)
-    with compat.use_mesh(mesh):
-        return mapped(model, events, carry)
+        check_vma=False)
+    return mapped(model, events, carry)
 
 
 # ---------------------------------------------------------------------------
@@ -470,7 +483,7 @@ def lane_specs(mesh, cfg, num_lanes: int, lane_axis: str = "data",
 @lru_cache(maxsize=8)
 def _default_lane_mesh(lane_axis: str):
     ndev = len(jax.devices())
-    return jax.make_mesh((ndev,), (lane_axis,))
+    return make_mesh((ndev,), (lane_axis,))
 
 
 @lru_cache(maxsize=32)
@@ -499,11 +512,11 @@ def _lanes_sharded_fn(cfg, mesh, num_lanes: int, lane_axis: str,
             new_c, outs = _merge_pattern_shards(new_c, outs, pax)
         return new_c, outs
 
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         local_run, mesh=mesh,
         in_specs=(specs["model"], specs["events"], specs["carry"], P(None)),
         out_specs=(specs["carry"], specs["out"]),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(mapped, donate_argnums=(2,))
 
 
@@ -530,5 +543,4 @@ def run_chunk_lanes_sharded(cfg, model, events, carry, start, mesh=None,
     fn = _lanes_sharded_fn(cfg, mesh, num_lanes, lane_axis, pattern_axis)
     if fn is None:
         return LN.run_chunk_lanes(cfg, model, events, carry, start)
-    with compat.use_mesh(mesh):
-        return fn(model, events, carry, jnp.asarray(start, jnp.int32)[None])
+    return fn(model, events, carry, jnp.asarray(start, jnp.int32)[None])

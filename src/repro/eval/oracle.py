@@ -18,9 +18,10 @@ Scope and fidelity:
     they are integer-valued, so "exact" is well-defined on any platform.
   * The simulated-time / overload-detector arithmetic is replicated in
     float32 with the engine's operation order, so shed decisions agree
-    with the jax engine on CPU for the seeded test configurations.  Keep
-    latency models LINEAR for bitwise agreement (``log2`` may differ by
-    an ulp between libm and XLA).
+    with the jax engine for the seeded test configurations.  Keep
+    latency models LINEAR for bitwise agreement on a CPU: ``_log2``
+    replays ``overload.log2`` op for op, but XLA's CPU backend fuses
+    multiply-adds, so NLOGN values may differ there by an ulp.
   * The engine's PM-BL shedder draws its random ρ-subset from
     ``jax.random``; a NumPy reimplementation cannot reproduce that
     stream, so — for PM-BL only — the oracle draws its scores through
@@ -74,9 +75,26 @@ class OracleResult:
     dropped: np.ndarray        # (n,) bool — E-BL input drop
 
 
+def _log2(x: f32) -> f32:
+    """f32 replica of ``overload.log2``, operation for operation."""
+    bits = int(np.asarray(x, f32).view(np.int32))
+    e = (bits >> 23) - 127
+    m = np.asarray((bits & 0x007FFFFF) | 0x3F800000,
+                   np.int32).view(f32)[()]
+    if m > f32(1.4142135):
+        m, e = f32(m * f32(0.5)), e + 1
+    s = f32(f32(m - f32(1.0)) / f32(m + f32(1.0)))
+    s2 = f32(s * s)
+    p = f32(f32(s2 * f32(1.0 / 9.0)) + f32(1.0 / 7.0))
+    for c in (1.0 / 5.0, 1.0 / 3.0, 1.0):
+        p = f32(f32(p * s2) + f32(c))
+    ln_m = f32(f32(f32(2.0) * s) * p)
+    return f32(f32(e) + f32(ln_m * f32(1.0 / eng.ovl.LN2)))
+
+
 def _predict(a: f32, b: f32, kind: int, n: f32) -> f32:
     """f32 replica of ``overload.predict_latency``."""
-    basis = n if kind == eng.ovl.LINEAR else f32(n * np.log2(f32(n + f32(1.0))))
+    basis = n if kind == eng.ovl.LINEAR else f32(n * _log2(f32(n + f32(1.0))))
     return f32(f32(a * basis) + b)
 
 
@@ -87,9 +105,9 @@ def _invert(a: f32, b: f32, kind: int, l_target: f32) -> f32:
         return t
     n = f32(max(t, f32(1.0)))
     for _ in range(16):
-        fn = f32(f32(n * np.log2(f32(n + f32(1.0)))) - t)
-        dfn = f32(np.log2(f32(n + f32(1.0)))
-                  + f32(n / f32(f32(n + f32(1.0)) * f32(np.log(2.0)))))
+        fn = f32(f32(n * _log2(f32(n + f32(1.0)))) - t)
+        dfn = f32(_log2(f32(n + f32(1.0)))
+                  + f32(n / f32(f32(n + f32(1.0)) * f32(eng.ovl.LN2))))
         n = f32(min(max(f32(n - f32(fn / max(dfn, f32(1e-9)))),
                         f32(0.0)), f32(1e12)))
     return n

@@ -11,7 +11,8 @@ the scratch carries across j.  Causal blocks above the diagonal are skipped
 via pl.when (no MXU work issued).
 
 TARGET: TPU (MXU-aligned cq/ck multiples of 128, f32 scratch).
-VALIDATED: interpret=True on CPU against ref.attention_ref (tests/).
+Checked interpreted on CPU against ref.attention_ref (tests/); not
+compiled for a TPU by tests/test_tpu_compile.py (no CEP cell uses it).
 """
 from __future__ import annotations
 
@@ -73,11 +74,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                              "interpret"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, cq: int = 128, ck: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """q: (B, Sq, H, D); k/v: (B, Sk, KVH, D).  GQA via KVH | H.
 
-    interpret=True executes the kernel body in Python on CPU (the validation
-    mode in this container); on TPU pass interpret=False.
+    ``interpret=True`` runs the kernel body through the Pallas interpreter
+    (any backend); ``interpret=False`` compiles it for a TPU.
     """
     B, Sq, H, D = q.shape
     _, Sk, KVH, Dv = v.shape

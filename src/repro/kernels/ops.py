@@ -3,7 +3,7 @@ backend-dispatch surface (``EngineConfig.backend`` — DESIGN.md §8).
 
 The engine never touches a kernel directly: it calls
 ``advance_seq_multi`` / ``pm_utilities_multi`` / ``shed_lowest_threshold``
-below, which run the Pallas kernels (compiled on TPU, ``interpret=True``
+below, which run the Pallas kernels (compiled on TPU, interpreted
 everywhere else via :func:`default_interpret`) and are bitwise-equivalent
 to the jnp reference path — the one-hot matmuls touch exactly one nonzero
 per row, and the histogram-threshold driver shares ``bucket_edges`` with
@@ -28,7 +28,11 @@ from repro.kernels.tiling import pad_to_tile, tile_pad  # noqa: F401
 
 
 def default_interpret() -> bool:
-    """Pallas kernels compile only on TPU; anywhere else run interpreted."""
+    """Pallas kernels compile only on TPU; anywhere else run interpreted.
+
+    Every kernel entry point takes ``interpret`` without a default: the
+    engine passes this, so a TPU run can never fall back to the
+    interpreter unnoticed."""
     return jax.default_backend() != "tpu"
 
 
@@ -36,7 +40,7 @@ def default_interpret() -> bool:
                                              "interpret"))
 def shed_lowest_pallas(active: jax.Array, state: jax.Array, r_w: jax.Array,
                        table: jax.Array, rho: jax.Array, *, bin_size: int,
-                       nbins: int = 64, interpret: bool = True) -> jax.Array:
+                       nbins: int = 64, interpret: bool) -> jax.Array:
     """Algorithm 2 via kernels: utility lookup → histogram-refinement
     threshold plan (``core.shedder.threshold_drop_mask`` with the Pallas
     histogram as its bucket counter).  O(N) end to end — the former
@@ -56,7 +60,7 @@ def advance_seq_multi(state: jax.Array, bind: jax.Array, active: jax.Array,
                       trans: jax.Array, ev_class: jax.Array,
                       ev_bind: jax.Array, final_state: jax.Array,
                       uses_binding: jax.Array, *,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool) -> jax.Array:
     """SEQ advance for the whole (P, N) PM store via ``nfa_advance_pallas``,
     one kernel launch per pattern (P is small and static).
 
@@ -79,7 +83,7 @@ def advance_seq_multi(state: jax.Array, bind: jax.Array, active: jax.Array,
 
 def pm_utilities_multi(state: jax.Array, r_w: jax.Array, active: jax.Array,
                        tables: jax.Array, bin_sizes: jax.Array, *,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """Fused utility lookup for the whole (P, N) store: one
     ``utility_lookup_dyn_pallas`` launch per pattern against its own
     (B, M) table and traced bin size.  Inactive slots get the kernel's
@@ -94,7 +98,7 @@ def pm_utilities_multi(state: jax.Array, r_w: jax.Array, active: jax.Array,
 
 def shed_lowest_threshold(active: jax.Array, utilities: jax.Array,
                           rho: jax.Array, *, nbins: int = 128,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool) -> jax.Array:
     """Histogram-threshold drop mask over flat (N,) utilities with the
     Pallas histogram kernel as the bucket counter (engine pallas path)."""
     hist = functools.partial(utility_histogram_pallas, nbins=nbins,

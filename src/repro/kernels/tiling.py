@@ -11,6 +11,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+LANES = 128   # TPU vector lane width: the last dim of every 2-D tile
+
 
 def tile_pad(tile: int, n: int) -> int:
     """Elements of tail padding needed to reach a multiple of ``tile``."""

@@ -29,9 +29,9 @@ import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import compile_cache
 from repro.configs import registry
 from repro.configs.shapes import SHAPES, ShapeSpec, applicable
-from repro.dist import compat as DC
 from repro.dist import sharding as SH
 from repro.launch import hlo_analysis as HA
 from repro.launch import mesh as M
@@ -73,7 +73,7 @@ def build_lowered(cfg: ModelConfig, shape: ShapeSpec, mesh, *,
     batch_s = registry.input_specs(cfg, shape)
 
     named = functools.partial(SH.named_tree, mesh)
-    with DC.use_mesh(mesh), SET.use_scheme(scheme, attn_flip):
+    with jax.set_mesh(mesh), SET.use_scheme(scheme, attn_flip):
         if shape.kind == "train":
             opt_s = jax.eval_shape(O.init_opt_state, params_s)
             pspecs, ospecs, bspecs = SH.train_specs(mesh, cfg, params_s,
@@ -184,6 +184,7 @@ def main(argv=None) -> int:
                          "heads don't divide the model axis")
     ap.add_argument("--json", default=None)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cells = []
     archs = registry.ARCH_IDS if args.all or not args.arch else (args.arch,)

@@ -179,9 +179,10 @@ def analyze(compiled, chips: int, model_flops: float = 0.0,
 
     # cost_analysis flops are per-partition (the module is the per-device
     # program) — per-chip time is direct.
-    compute_s = flops / M.PEAK_FLOPS_BF16
-    memory_s = byts / M.HBM_BW
-    collective_s = coll.total_bytes / M.ICI_BW_PER_LINK
+    pk = M.peaks()
+    compute_s = flops / pk["flops_bf16"]
+    memory_s = byts / pk["hbm_bw"]
+    collective_s = coll.total_bytes / pk["ici_bw_per_link"]
     dominant = max(
         (("compute", compute_s), ("memory", memory_s),
          ("collective", collective_s)), key=lambda kv: kv[1])[0]

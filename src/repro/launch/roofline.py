@@ -134,9 +134,10 @@ def roofline_cell(cfg: ModelConfig, shape: ShapeSpec, mesh, chips: int, *,
     byts = b1 + r * (b2 - b1)
     coll = coll1.plus(coll2.minus(coll1).scaled(r))
 
-    compute_s = flops / M.PEAK_FLOPS_BF16
-    memory_s = byts / M.HBM_BW
-    collective_s = coll.total_bytes / M.ICI_BW_PER_LINK
+    pk = M.peaks()
+    compute_s = flops / pk["flops_bf16"]
+    memory_s = byts / pk["hbm_bw"]
+    collective_s = coll.total_bytes / pk["ici_bw_per_link"]
     dominant = max(
         (("compute", compute_s), ("memory", memory_s),
          ("collective", collective_s)), key=lambda kv: kv[1])[0]

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import registry
 from repro.dist import sharding as SH
 from repro.launch import mesh as M
@@ -41,6 +42,7 @@ def main(argv=None) -> int:
     ap.add_argument("--slo", type=float, default=1.0)
     ap.add_argument("--max-len", type=int, default=96)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = registry.get_smoke_config(args.arch)
     params = T.init_params(cfg, jax.random.PRNGKey(0))

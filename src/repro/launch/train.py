@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import registry
 from repro.dist import sharding as SH
 from repro.launch import mesh as M
@@ -68,6 +69,7 @@ def main(argv=None) -> int:
     ap.add_argument("--no-shard", action="store_true",
                     help="skip explicit in/out shardings (debug only)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = (registry.get_smoke_config(args.arch) if args.smoke
            else registry.get_config(args.arch))

@@ -72,12 +72,8 @@ def constrain(x, *axes):
     Axis entries may be tuples (e.g. ("pod", "data")); "data" is auto-
     upgraded to ("pod", "data") when a pod axis exists in the mesh.
     """
-    try:
-        from repro.dist import compat
-        mesh = compat.get_active_mesh()
-        if mesh is None or not mesh.axis_names:
-            return x
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     names = set(mesh.axis_names)
     sch = scheme()

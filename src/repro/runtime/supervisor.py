@@ -32,6 +32,7 @@ import sys
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.cep import engine as eng
 from repro.cep import patterns as pat
 from repro.cep import runner
@@ -167,7 +168,9 @@ def run_service(spec: dict, persist_dir: str | None = None,
     for s in range(start_push * push, n, push):
         srt.push(chunker.slice_events(ev, s, min(s + push, n)))
     srt.flush()
+    dev = jax.devices()[0]
     return {
+        "platform": dev.platform, "device_kind": dev.device_kind,
         "carry_sha": carry_sha(srt),
         "counters": semantic_counters(srt),
         "matches": [sorted([list(map(int, m)) for m in s])
@@ -180,9 +183,11 @@ def run_service(spec: dict, persist_dir: str | None = None,
 def child_main(argv: list[str]) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--spec", required=True, help="workload spec JSON")
-    ap.add_argument("--dir", required=True, help="persistence directory")
+    ap.add_argument("--dir", default=None,
+                    help="persistence directory (omit: no persistence)")
     ap.add_argument("--out", required=True, help="final report JSON path")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     FT.install_kill_from_env()
     report = run_service(json.loads(args.spec), persist_dir=args.dir)
     PS.atomic_write(args.out,
@@ -192,27 +197,43 @@ def child_main(argv: list[str]) -> int:
 
 class Supervisor:
     """Launch the child worker, expect the armed SIGKILL, relaunch until
-    the report file appears."""
+    the report file appears.
+
+    Every run of the workload — the uninterrupted reference included
+    (:meth:`reference`) — happens in a child, so the supervising process
+    never initializes a JAX backend: on a TPU host the device belongs to
+    one process at a time, and a parent holding it would block the
+    children.  Each report names the platform its child ran on."""
 
     def __init__(self, workdir: str):
         self.workdir = workdir
         self.attempts: list[dict] = []
 
-    def _launch(self, spec: dict, out: str, kill: str | None):
+    def _launch(self, spec: dict, out: str, kill: str | None,
+                persist: bool = True):
         env = dict(os.environ)
         env["PYTHONPATH"] = os.pathsep.join(
             [os.path.dirname(os.path.dirname(os.path.dirname(
                 os.path.abspath(__file__))))]
             + env.get("PYTHONPATH", "").split(os.pathsep))
-        env.setdefault("JAX_PLATFORMS", "cpu")
         env.pop(FT.KILL_ENV, None)
         if kill is not None:
             env[FT.KILL_ENV] = kill
         cmd = [sys.executable, "-m", "repro.runtime.supervisor", "--child",
-               "--spec", json.dumps(spec),
-               "--dir", os.path.join(self.workdir, "persist"),
-               "--out", out]
+               "--spec", json.dumps(spec), "--out", out]
+        if persist:
+            cmd += ["--dir", os.path.join(self.workdir, "persist")]
         return subprocess.run(cmd, env=env, capture_output=True, text=True)
+
+    def reference(self, spec: dict) -> dict:
+        """The uninterrupted run (no persistence, no kill), in a child."""
+        out = os.path.join(self.workdir, "reference.json")
+        proc = self._launch(spec, out, kill=None, persist=False)
+        if proc.returncode != 0:
+            raise RuntimeError(f"reference child failed rc={proc.returncode}"
+                               f":\n{proc.stderr[-2000:]}")
+        with open(out, "rb") as f:
+            return json.loads(f.read())
 
     def run(self, spec: dict, kill: str | None,
             max_restarts: int = 2) -> dict:

@@ -20,10 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# Version-compatible shard_map (jax.shard_map moved out of experimental in
-# newer jax): callers — including the tests — should use this symbol.
-from repro.dist.compat import shard_map  # noqa: F401
-
 PyTree = Any
 
 

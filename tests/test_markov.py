@@ -183,6 +183,23 @@ class TestOverloadDetector:
                 m, overload.predict_latency(m, jnp.float32(n))))
             assert abs(got - n) / n < 1e-2
 
+    def test_log2_accuracy(self):
+        """The add/multiply/divide log2 the block kernel shares with the
+        xla step: exact on powers of two, within 2.5 ulps elsewhere."""
+        rng = np.random.default_rng(0)
+        x = np.concatenate([
+            np.arange(1, 4097, dtype=np.float32),
+            (1 + rng.random(4096) * 10 ** rng.uniform(0, 9, 4096)
+             ).astype(np.float32)])
+        got = np.asarray(jax.jit(overload.log2)(x), np.float64)
+        ref = np.log2(x.astype(np.float64))
+        ulp = np.spacing(np.abs(ref).astype(np.float32)).astype(np.float64)
+        nz = ref != 0
+        assert (np.abs(got - ref)[nz] / ulp[nz]).max() <= 2.5
+        assert got[0] == 0.0
+        for k in range(31):
+            assert float(overload.log2(jnp.float32(2.0 ** k))) == k
+
     def test_algorithm1_rho(self):
         """Alg. 1: rho drops exactly to the sustainable PM count."""
         f = overload.LatencyModel(a=jnp.float32(1e-3), b=jnp.float32(0.0),

@@ -459,8 +459,9 @@ class TestSupervisorSigkill:
                 "n": 1024, "push": 256, "chunk": 128, "max_pms": 32,
                 "rate_mult": 3.0, "refresh_every": 4, "snapshot_every": 3,
                 "min_observations": 64.0}
-        ref = SV.run_service(spec, persist_dir=None)
-        res = SV.Supervisor(str(tmp_path)).run(spec, kill="chunk:3")
+        sup = SV.Supervisor(str(tmp_path))
+        ref = sup.reference(spec)      # the uninterrupted run, in a child
+        res = sup.run(spec, kill="chunk:3")
         assert res["killed"] and res["recovered"]
         assert res["attempts"][0]["returncode"] == -9
         rep = res["report"]
@@ -468,6 +469,7 @@ class TestSupervisorSigkill:
         assert rep["matches"] == ref["matches"]
         assert rep["counters"] == ref["counters"]
         assert rep["events_processed"] == ref["events_processed"]
+        assert rep["platform"] == ref["platform"]
         # Satellite: a real recovery dumps the restored telemetry.
         dump = os.path.join(str(tmp_path), "persist",
                             "telemetry_recovered.json")

@@ -13,7 +13,7 @@ from repro.models import transformer as T
 @pytest.fixture(scope="module")
 def mesh():
     # AbstractMesh with the production topology: no devices needed for
-    # spec construction (SH.abstract_mesh bridges the 0.4.x/0.5+ ctor).
+    # spec construction (SH.abstract_mesh: Auto axes, no devices).
     from repro.launch import mesh as M
     return M.make_abstract_production_mesh()
 

@@ -74,6 +74,27 @@ class TestThresholdDropMask:
             tol = max(span / 128.0 ** 3, 1e-6)
             assert u[dropped].max() <= u[kept].min() + tol * 1.01
 
+    @given(st.integers(0, 500), st.integers(0, 2000))
+    @settings(max_examples=25, deadline=None)
+    def test_rows_form_bitwise_equals_flat(self, seed, rho):
+        """The block kernel's (R, N) restatement drops exactly the flat
+        select's slots (row-major order), ties and all."""
+        rng = np.random.default_rng(seed)
+        R, N = int(rng.integers(1, 4)), int(rng.integers(1, 150))
+        active = rng.random((R, N)) < rng.uniform(0.1, 1.0)
+        if seed % 3 == 0:
+            u = rng.integers(0, 4, (R, N)).astype(np.float32)
+        else:
+            u = (rng.random((R, N)) * 10 ** rng.uniform(-2, 3)).astype(
+                np.float32)
+        flat = jax.jit(shedder.threshold_drop_mask)(
+            jnp.asarray(active.reshape(-1)), jnp.asarray(u.reshape(-1)),
+            jnp.int32(rho))
+        rows = jax.jit(shedder.threshold_drop_mask_rows)(
+            jnp.asarray(active), jnp.asarray(u), jnp.int32(rho))
+        np.testing.assert_array_equal(np.asarray(rows).reshape(-1),
+                                      np.asarray(flat))
+
     def test_all_ties_bitwise_equals_oracle(self):
         """Once every candidate holds one f32 value, the index tie-break
         IS the stable argsort order — bitwise equality."""

@@ -99,10 +99,8 @@ class TestCompression:
         n = len(jax.devices())
         g = jnp.stack([jnp.full((64,), float(i + 1)) for i in range(n)])
         e = jnp.zeros_like(g)
-        # COMP.shard_map: version-compatible shim (jax.shard_map is not
-        # public on 0.4.x).
-        mfn = COMP.shard_map(f, mesh=mesh, in_specs=(P("d"), P("d")),
-                             out_specs=(P("d"), P("d")))
+        mfn = jax.shard_map(f, mesh=mesh, in_specs=(P("d"), P("d")),
+                            out_specs=(P("d"), P("d")))
         mean, _ = mfn(g, e)
         expect = np.mean([i + 1 for i in range(n)])
         np.testing.assert_allclose(np.asarray(mean[0]), expect, rtol=1e-2)
