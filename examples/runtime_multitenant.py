@@ -59,14 +59,15 @@ def main() -> int:
     for s in range(0, n, push):
         batch = RT.slice_events(evL, s, min(s + push, n), axis=1)
         for st in mt.push(batch, flush=(s + push >= n)):
-            print(f"{st.chunk_index:5d} {st.events_per_s:10.0f} "
+            print(f"{st.chunk_index:5d} {st.n_events / st.wall_s:10.0f} "
                   f"{st.l_e_p99:9.4f} {st.pms_shed:9.0f} "
                   f"{st.completions:12.0f} "
                   f"{'yes' if st.refreshed else '':>8s}")
 
     agg = mt.telemetry.aggregate()
     merged = mt.merged_carry()
-    print(f"\naggregate: {agg['events_per_s']:.0f} events/s over "
+    rate = agg["n_events"] / agg["wall_s"]
+    print(f"\naggregate: {rate:.0f} events/s over "
           f"{agg['n_events']} events in {agg['n_chunks']} chunks; "
           f"{agg['refreshes']} refresh rounds")
     print("per-tenant completions:",

@@ -1112,11 +1112,24 @@ def match_sets(outs: StepOut, start: int = 0) -> list[set[tuple]]:
     the global index of the first event (chunked runs pass their chunk
     start and union the per-chunk sets).
     """
-    m_open = np.asarray(outs.match_open)         # (n, P, N)
+    return decode_matches(*match_columns(outs), start)
+
+
+def match_columns(outs: StepOut) -> tuple[np.ndarray, np.ndarray]:
+    """The emitted match columns ``(match_open, match_bind)``, each
+    ``(n, P, N)``, on the host: the transfer half of ``match_sets``."""
+    m_open = np.asarray(outs.match_open)
     m_bind = np.asarray(outs.match_bind)
     if m_open.ndim != 3 or m_open.shape[-1] == 0:
         raise ValueError("run had cfg.emit_matches off — no match identity "
                          "was emitted (match fields are zero-width)")
+    return m_open, m_bind
+
+
+def decode_matches(m_open: np.ndarray, m_bind: np.ndarray,
+                   start: int = 0) -> list[set[tuple]]:
+    """Per-pattern match-identity sets from ``match_columns``: the host
+    half of ``match_sets``."""
     n, P, _ = m_open.shape
     out: list[set[tuple]] = [set() for _ in range(P)]
     ev, p, slot = np.nonzero(m_open >= 0)

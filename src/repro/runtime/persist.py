@@ -42,6 +42,7 @@ import jax
 import numpy as np
 
 from repro.cep import engine as eng
+from repro.runtime import telemetry as TM
 
 SNAP_MAGIC = b"PSPSNAP\x01"
 SNAP_VERSION = 1
@@ -161,26 +162,32 @@ def event_template() -> eng.EventBatch:
 
 # -- snapshot container -----------------------------------------------------
 def build_snapshot_bytes(chunk_index: int, control: dict,
-                         sections: dict) -> bytes:
+                         sections: dict, telemetry: TM.TelemetryLog) -> bytes:
     """``MAGIC | <u32 version, u32 header_len> | header JSON | payload |
     u32 CRC32(everything after MAGIC)``.  ``sections`` maps name →
-    pytree; None values are skipped."""
+    pytree; None values are skipped.  The encoding and framing are the
+    span ``rt.snapshot.encode`` of ``telemetry``, the header JSON
+    ``rt.snapshot.control``."""
     secmeta, blobs, off = {}, [], 0
-    for name in sorted(sections):
-        tree = sections[name]
-        if tree is None:
-            continue
-        man, blob = encode_tree(tree)
-        secmeta[name] = {"manifest": man, "offset": off,
-                         "nbytes": len(blob)}
-        blobs.append(blob)
-        off += len(blob)
+    with telemetry.span("rt.snapshot.encode"):
+        for name in sorted(sections):
+            tree = sections[name]
+            if tree is None:
+                continue
+            man, blob = encode_tree(tree)
+            secmeta[name] = {"manifest": man, "offset": off,
+                             "nbytes": len(blob)}
+            blobs.append(blob)
+            off += len(blob)
     header = {"format": "pspice-snapshot", "version": SNAP_VERSION,
               "chunk_index": int(chunk_index), "control": control,
               "sections": secmeta}
-    hj = json.dumps(header, sort_keys=True).encode()
-    body = struct.pack("<II", SNAP_VERSION, len(hj)) + hj + b"".join(blobs)
-    return SNAP_MAGIC + body + struct.pack("<I", zlib.crc32(body))
+    with telemetry.span("rt.snapshot.control"):
+        hj = json.dumps(header, sort_keys=True).encode()
+    with telemetry.span("rt.snapshot.encode"):
+        body = struct.pack("<II", SNAP_VERSION, len(hj)) + hj \
+            + b"".join(blobs)
+        return SNAP_MAGIC + body + struct.pack("<I", zlib.crc32(body))
 
 
 def parse_snapshot_bytes(data: bytes, path: str = "<bytes>"
@@ -245,11 +252,15 @@ def atomic_write(path: str, data: bytes) -> None:
 
 
 class SnapshotStore:
-    """Generation-rotated snapshot files: ``snap-<chunk>.ckpt``."""
+    """Generation-rotated snapshot files: ``snap-<chunk>.ckpt``.  Saves
+    are spans and counters of ``telemetry`` (``build_snapshot_bytes``;
+    ``rt.snapshot.write``, ``snapshot_bytes``)."""
 
-    def __init__(self, dir: str, keep_generations: int = 3):
+    def __init__(self, dir: str, telemetry: TM.TelemetryLog,
+                 keep_generations: int = 3):
         self.dir = dir
         self.keep = max(1, keep_generations)
+        self.telemetry = telemetry
         os.makedirs(dir, exist_ok=True)
 
     def paths(self) -> list[str]:
@@ -258,7 +269,8 @@ class SnapshotStore:
     def save(self, chunk_index: int, control: dict, sections: dict) -> str:
         from repro.runtime import faults as FT
 
-        data = build_snapshot_bytes(chunk_index, control, sections)
+        data = build_snapshot_bytes(chunk_index, control, sections,
+                                    self.telemetry)
         path = os.path.join(self.dir, f"snap-{int(chunk_index):010d}.ckpt")
         ks = FT.active_kill_switch()
         if ks is not None and ks.pending("snapshot"):
@@ -270,8 +282,10 @@ class SnapshotStore:
                 f.flush()
                 os.fsync(f.fileno())
             ks.kill()
-        atomic_write(path, data)
-        self._prune()
+        with self.telemetry.span("rt.snapshot.write"):
+            atomic_write(path, data)
+            self._prune()
+        self.telemetry.count("snapshot_bytes", len(data))
         return path
 
     def _prune(self) -> None:
@@ -308,11 +322,15 @@ class WriteAheadLog:
     durable against process death.  A snapshot stores the first
     unabsorbed id; replay never re-appends (the records are already on
     disk), and the next post-recovery append opens a fresh segment.
+    Appends are spans of ``telemetry`` (``rt.wal.append``,
+    ``rt.wal.fsync``).
     """
 
-    def __init__(self, dir: str, fsync_every: int = 1):
+    def __init__(self, dir: str, telemetry: TM.TelemetryLog,
+                 fsync_every: int = 1):
         self.dir = dir
         self.fsync_every = fsync_every
+        self.telemetry = telemetry
         os.makedirs(dir, exist_ok=True)
         self._f = None
         self._appends = 0
@@ -336,22 +354,26 @@ class WriteAheadLog:
         return self._next_id
 
     def append(self, events) -> int:
-        if self._f is None:
-            path = os.path.join(self.dir, f"wal-{self._next_seq:08d}.seg")
-            self._next_seq += 1
-            self._f = open(path, "wb")
-            self._f.write(WAL_MAGIC)
-        man, blob = encode_tree(events)
-        mj = json.dumps(man, sort_keys=True).encode()
-        rid = self._next_id
-        head = _REC_HEAD.pack(_REC_MAGIC, rid, len(mj), len(blob))
-        rec = head + mj + blob
-        self._f.write(rec + struct.pack("<I", zlib.crc32(rec[4:])))
-        self._f.flush()
+        tm = self.telemetry
+        with tm.span("rt.wal.append"):
+            if self._f is None:
+                path = os.path.join(self.dir,
+                                    f"wal-{self._next_seq:08d}.seg")
+                self._next_seq += 1
+                self._f = open(path, "wb")
+                self._f.write(WAL_MAGIC)
+            man, blob = encode_tree(events)
+            mj = json.dumps(man, sort_keys=True).encode()
+            rid = self._next_id
+            head = _REC_HEAD.pack(_REC_MAGIC, rid, len(mj), len(blob))
+            rec = head + mj + blob
+            self._f.write(rec + struct.pack("<I", zlib.crc32(rec[4:])))
+            self._f.flush()
         self._appends += 1
         if self.fsync_every > 0 \
                 and self._appends % self.fsync_every == 0:
-            os.fsync(self._f.fileno())
+            with tm.span("rt.wal.fsync"):
+                os.fsync(self._f.fileno())
         self._next_id = rid + 1
         return rid
 
@@ -410,10 +432,11 @@ def _iter_segment(path: str):
 
 
 class Persistence:
-    """One runtime's durability bundle: store + WAL under one dir."""
+    """One runtime's durability bundle: store + WAL under one dir, both
+    charging their spans and counters to ``telemetry``."""
 
-    def __init__(self, cfg: PersistConfig):
+    def __init__(self, cfg: PersistConfig, telemetry: TM.TelemetryLog):
         self.cfg = cfg
         os.makedirs(cfg.dir, exist_ok=True)
-        self.store = SnapshotStore(cfg.dir, cfg.keep_generations)
-        self.wal = WriteAheadLog(cfg.dir, cfg.wal_fsync_every)
+        self.store = SnapshotStore(cfg.dir, telemetry, cfg.keep_generations)
+        self.wal = WriteAheadLog(cfg.dir, telemetry, cfg.wal_fsync_every)

@@ -25,6 +25,7 @@ import numpy as np
 from repro.cep import engine as eng
 from repro.cep import patterns as pat
 from repro.core import markov, overload as ovl, utility as util
+from repro.runtime import telemetry as TM
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,13 +145,41 @@ def refit_latency_model(carry: eng.Carry) -> ovl.LatencyModel:
 def refresh_model(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
                   model: eng.EngineModel, carry: eng.Carry,
                   rcfg: RefreshConfig, state: RefreshState,
+                  telemetry: TM.TelemetryLog,
                   ) -> tuple[eng.EngineModel, eng.Carry, bool]:
     """Re-estimate the utility tables (+ latency model) from the carry.
 
     Returns ``(model, carry, refreshed)``; the carry comes back with its
     observation accumulators decayed by ``rcfg.decay`` when a refresh ran.
-    Mutates ``state`` (refresh/skip counters, deployed chains).
+    Mutates ``state`` (refresh/skip counters, deployed chains).  Its three
+    phases are spans of ``telemetry``: ``rt.refresh.obs`` (observations
+    to the host, gates), ``rt.refresh.fit`` (chains, drift test, tables,
+    latency refit) and ``rt.refresh.deploy``.
     """
+    with telemetry.span("rt.refresh.obs"):
+        ready = _gate(carry, rcfg, state)
+    if not ready:
+        return model, carry, False
+    with telemetry.span("rt.refresh.fit"):
+        fit = _fit(specs, cfg, model, carry, rcfg, state)
+    if fit is None:
+        return model, carry, False
+    fresh, ut_stacked, ut_bins, f_model = fit
+    with telemetry.span("rt.refresh.deploy"):
+        model = model._replace(ut_tables=ut_stacked, ut_bins=ut_bins,
+                               f_model=f_model)
+        if rcfg.decay < 1.0:
+            carry = carry._replace(obs_counts=carry.obs_counts * rcfg.decay,
+                                   obs_rewards=carry.obs_rewards * rcfg.decay)
+    state.last_T = fresh
+    state.refresh_count += 1
+    return model, carry, True
+
+
+def _gate(carry: eng.Carry, rcfg: RefreshConfig,
+          state: RefreshState) -> bool:
+    """The carry's observations read on the host: False where they are
+    non-finite or too few, and the refresh is skipped."""
     # NaN-safe gate (DESIGN.md §12): a poisoned accumulator must SKIP the
     # refresh, not deploy corrupt tables.  Note `nan < threshold` is False
     # — the min-observation gate alone would wave NaNs straight through.
@@ -158,12 +187,17 @@ def refresh_model(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
     obs_r = np.asarray(carry.obs_rewards)
     if not (np.isfinite(obs_c).all() and np.isfinite(obs_r).all()):
         state.skipped_nonfinite += 1
-        return model, carry, False
-    total_obs = float(obs_c.sum())
-    if total_obs < rcfg.min_observations:
+        return False
+    if float(obs_c.sum()) < rcfg.min_observations:
         state.skipped_obs += 1
-        return model, carry, False
+        return False
+    return True
 
+
+def _fit(specs, cfg, model, carry, rcfg, state):
+    """(chains, tables, bins, latency model) fitted from the observations,
+    or None where the drift test or a non-finite product skips the
+    refresh."""
     Ts, Rs = estimate_chains(specs, cfg, carry.obs_counts, carry.obs_rewards)
     fresh = _stack_T(Ts, cfg.max_states)
     if rcfg.drift_threshold > 0 and state.last_T is not None:
@@ -173,7 +207,7 @@ def refresh_model(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
             for p in range(len(specs))))
         if mse <= rcfg.drift_threshold:
             state.skipped_drift += 1
-            return model, carry, False
+            return None
 
     tables = [util.build_utility_table(
         T, R, window_size=spec.window_size, bin_size=rcfg.bin_size,
@@ -195,7 +229,7 @@ def refresh_model(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
     # degenerates the regression) keeps the deployed model.
     if not np.isfinite(np.asarray(ut_stacked)).all():
         state.skipped_nonfinite += 1
-        return model, carry, False
+        return None
     f_model = model.f_model
     if rcfg.refit_latency:
         cand = refit_latency_model(carry)
@@ -204,11 +238,4 @@ def refresh_model(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
             f_model = cand
         else:
             state.skipped_nonfinite += 1
-    model = model._replace(ut_tables=ut_stacked, ut_bins=ut_bins,
-                           f_model=f_model)
-    if rcfg.decay < 1.0:
-        carry = carry._replace(obs_counts=carry.obs_counts * rcfg.decay,
-                               obs_rewards=carry.obs_rewards * rcfg.decay)
-    state.last_T = fresh
-    state.refresh_count += 1
-    return model, carry, True
+    return fresh, ut_stacked, ut_bins, f_model

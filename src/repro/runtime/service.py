@@ -277,6 +277,7 @@ class StreamRuntime:
         self.refresh_state = RF.RefreshState()
         self._buf = chunker.ChunkBuffer(self.rt.chunk_size)
         self._chunk_i = 0
+        self._pushes = 0             # push sequence number (span metadata)
         self.events_processed = 0
         self._snapshot: dict[str, float] | None = None
         self._init_resilience()
@@ -295,7 +296,7 @@ class StreamRuntime:
         self._quarantined = False
         self._event_cursor = 0       # global index after the last chunk
         self.quarantine_dropped = 0  # events refused while quarantined
-        self.persist = PS.Persistence(rt.persist) \
+        self.persist = PS.Persistence(rt.persist, self.telemetry) \
             if rt.persist is not None else None
         self._last_snap_chunk = 0
         self._replaying = False         # True while re-pushing WAL records
@@ -366,7 +367,8 @@ class StreamRuntime:
         gcfg = self.rt.guard
         if self._chunk_i % gcfg.check_every_chunks != 0:
             return
-        viols = self.guard.check(self.carry, self.model)
+        with self.telemetry.span("rt.guard.check"):
+            viols = self.guard.check(self.carry, self.model)
         if viols:
             for v in viols:
                 self.telemetry.record_event("guard_violation",
@@ -375,8 +377,9 @@ class StreamRuntime:
                 self._guard_restore(viols)
         elif self._chunk_i % gcfg.checkpoint_every_chunks == 0:
             # Check-then-save: a poisoned state is never checkpointed.
-            self.guard.save(self.carry, self.model, self._chunk_i,
-                            control=self._control_state(scope="guard"))
+            with self.telemetry.span("rt.guard.checkpoint"):
+                self.guard.save(self.carry, self.model, self._chunk_i,
+                                control=self._control_state(scope="guard"))
 
     def _guard_restore(self, viols: list[GD.GuardViolation]) -> None:
         self.carry, self.model = self.guard.restore(self.carry, self.model)
@@ -401,7 +404,8 @@ class StreamRuntime:
         chaos harness); restores on violation per the guard config."""
         if self.guard is None:
             raise ValueError("guard_now needs rt.guard (GuardConfig)")
-        viols = self.guard.check(self.carry, self.model)
+        with self.telemetry.span("rt.guard.check"):
+            viols = self.guard.check(self.carry, self.model)
         if viols:
             for v in viols:
                 self.telemetry.record_event("guard_violation",
@@ -483,7 +487,7 @@ class StreamRuntime:
         self._event_cursor = int(d["event_cursor"])
         self.events_processed = int(d["events_processed"])
         self._snapshot = d["counter_snapshot"]
-        self.telemetry = TM.TelemetryLog.from_json(d["telemetry"])
+        self.telemetry.load_json(d["telemetry"])
         states = [RF.RefreshState.from_control(s) for s in d["refresh"]]
         if isinstance(self.refresh_state, list):
             self.refresh_state = states
@@ -506,7 +510,8 @@ class StreamRuntime:
         if self.persist is None:
             raise ValueError("snapshot_now needs rt.persist "
                              "(PersistConfig)")
-        control = self._control_state("full")
+        with self.telemetry.span("rt.snapshot.control"):
+            control = self._control_state("full")
         # First WAL record NOT absorbed into this snapshot: during normal
         # operation every appended record has been pushed; during replay
         # the cursor tracks the record being re-pushed, so a snapshot cut
@@ -608,8 +613,10 @@ class StreamRuntime:
 
     # -- chunk execution (overridden by the lane runtime) -------------------
     def _run(self, chunk: eng.EventBatch, start: int):
-        return eng.run_engine_chunk(self.cfg, self.model, chunk, self.carry,
-                                    eng.wrap_event_index(start))
+        with self.telemetry.span("rt.dispatch"):
+            return eng.run_engine_chunk(self.cfg, self.model, chunk,
+                                        self.carry,
+                                        eng.wrap_event_index(start))
 
     def _refresh_on(self) -> bool:
         r = self.rt.refresh
@@ -622,7 +629,7 @@ class StreamRuntime:
         FT.kill_point("refresh")
         self.model, self.carry, did = RF.refresh_model(
             self.specs, self.cfg, self.model, self.carry, self.rt.refresh,
-            self.refresh_state)
+            self.refresh_state, self.telemetry)
         return did
 
     # -- ingestion ----------------------------------------------------------
@@ -644,14 +651,20 @@ class StreamRuntime:
         With ``rt.persist`` the batch is appended (and flushed) to the
         write-ahead log BEFORE any processing — admission included — so
         a crash mid-push replays the whole push through this same path
-        and re-derives every decision (DESIGN.md §13)."""
-        if self.persist is not None and not self._replaying:
-            self.persist.wal.append(events)
-        stats = self._ingest_events(events)
-        if flush:
-            stats += self.flush()
-        if self.persist is not None and not self._replaying:
-            self._maybe_snapshot()
+        and re-derives every decision (DESIGN.md §13).
+
+        The push is the root span (``rt.push``, its sequence number as
+        metadata) of the host spans that ``telemetry`` charges to the
+        chunks it runs."""
+        self._pushes += 1
+        with self.telemetry.span(TM.PUSH_SPAN, seq=self._pushes):
+            if self.persist is not None and not self._replaying:
+                self.persist.wal.append(events)
+            stats = self._ingest_events(events)
+            if flush:
+                stats += self.flush()
+            if self.persist is not None and not self._replaying:
+                self._maybe_snapshot()
         return stats
 
     def _ingest_events(self, events: eng.EventBatch) -> list[TM.ChunkStats]:
@@ -662,10 +675,18 @@ class StreamRuntime:
             # the refusal ticked the ladder out of quarantine: fall
             # through and ingest this push normally
         if self.ingest is not None:
-            self._record_admission(self.ingest.offer(events))
+            with self.telemetry.span("rt.admit"):
+                self._record_admission(self.ingest.offer(events))
             return self._pump()
-        start, region, n_chunks = self._buf.push_region(events)
-        return self._run_region(start, region, n_chunks)
+        return self._run_region(*self._push_region(events))
+
+    def _push_region(self, events: eng.EventBatch):
+        with self.telemetry.span("rt.slice"):
+            return self._buf.push_region(events)
+
+    def _take(self, budget: int | None, drain: bool):
+        with self.telemetry.span("rt.admit"):
+            return self.ingest.take(budget, drain=drain)
 
     def _quarantine_refuse(self, events: eng.EventBatch) -> None:
         n = chunker.num_events(events, self._buf.axis)
@@ -676,11 +697,10 @@ class StreamRuntime:
     def _pump(self, drain: bool = False) -> list[TM.ChunkStats]:
         limit = self.rt.ingest.pump_chunks
         budget = None if limit <= 0 else limit * self.rt.chunk_size
-        ev = self.ingest.take(budget, drain=drain)
+        ev = self._take(budget, drain)
         if ev is None:
             return []
-        start, region, n_chunks = self._buf.push_region(ev)
-        return self._run_region(start, region, n_chunks)
+        return self._run_region(*self._push_region(ev))
 
     def flush(self) -> list[TM.ChunkStats]:
         """Drain the ingest queue, then the buffered remainder as one
@@ -688,11 +708,10 @@ class StreamRuntime:
         stats: list[TM.ChunkStats] = []
         if self.ingest is not None:
             while not self._quarantined:
-                ev = self.ingest.take(None, drain=True)
+                ev = self._take(None, drain=True)
                 if ev is None:
                     break
-                start, region, n_chunks = self._buf.push_region(ev)
-                stats += self._run_region(start, region, n_chunks)
+                stats += self._run_region(*self._push_region(ev))
         stats += [self._run_piece(start, chunk)
                   for start, chunk in self._buf.drain()]
         return stats
@@ -717,8 +736,12 @@ class StreamRuntime:
                     self._chunks_to_boundary())
             # push_region owns the region (never aliases the caller's
             # batch), so the common whole-region group skips the slice.
-            piece = region if j == 0 and g == n_chunks else \
-                chunker.slice_events(region, j * cs, (j + g) * cs, axis)
+            if j == 0 and g == n_chunks:
+                piece = region
+            else:
+                with self.telemetry.span("rt.slice"):
+                    piece = chunker.slice_events(region, j * cs,
+                                                 (j + g) * cs, axis)
             if g == 1:
                 stats.append(self._run_piece(start + j * cs, piece))
             else:
@@ -728,11 +751,12 @@ class StreamRuntime:
 
     # -- grouped execution (one dispatch per chunk group) -------------------
     def _run_grouped(self, piece: eng.EventBatch, start: int, g: int):
-        ev = jax.tree.map(
-            lambda x: x.reshape((g, -1) + x.shape[1:]), piece)
-        return _run_group_single(self.cfg, self.model, ev, self.carry,
-                                 eng.wrap_event_index(start),
-                                 self.rt.scan_unroll)
+        with self.telemetry.span("rt.dispatch"):
+            ev = jax.tree.map(
+                lambda x: x.reshape((g, -1) + x.shape[1:]), piece)
+            return _run_group_single(self.cfg, self.model, ev, self.carry,
+                                     eng.wrap_event_index(start),
+                                     self.rt.scan_unroll)
 
     def _run_group(self, start: int, piece: eng.EventBatch,
                    g: int) -> list[TM.ChunkStats]:
@@ -740,9 +764,13 @@ class StreamRuntime:
         cs = self.rt.chunk_size
         n_lanes = 1 if self._buf.axis == 0 \
             else jax.tree.leaves(piece)[0].shape[0]
+        self.telemetry.begin_chunk()
         t0 = time.perf_counter()
         self.carry, vecs = self._run_grouped(piece, start, g)
-        vecs = np.asarray(vecs)                # ONE transfer for g chunks
+        with self.telemetry.span("rt.device_wait"):
+            vecs = jax.block_until_ready(vecs)
+        with self.telemetry.span("rt.stats"):
+            vecs = np.asarray(vecs)            # ONE transfer for g chunks
         wall = time.perf_counter() - t0
         FT.kill_point("chunk")
         out = []
@@ -759,8 +787,8 @@ class StreamRuntime:
         out[-1].refreshed = refreshed
         out[-1].refresh_wall_s = time.perf_counter() - t1
         self._snapshot = before
+        self.telemetry.extend(out)
         for s in out:
-            self.telemetry.append(s)
             self.events_processed += s.n_events
         self._event_cursor = start + g * cs
         self._after_chunk(out)
@@ -776,9 +804,15 @@ class StreamRuntime:
         n = chunker.num_events(chunk, self._buf.axis)
         n_lanes = 1 if self._buf.axis == 0 \
             else jax.tree.leaves(chunk)[0].shape[0]
+        self.telemetry.begin_chunk()
         t0 = time.perf_counter()
         self.carry, outs = self._run(chunk, start)
-        vec = np.asarray(TM.device_chunk_stats(outs, self.carry))
+        with self.telemetry.span("rt.stats"):
+            vec = TM.device_chunk_stats(outs, self.carry)
+        with self.telemetry.span("rt.device_wait"):
+            vec = jax.block_until_ready(vec)
+        with self.telemetry.span("rt.stats"):
+            vec = np.asarray(vec)
         wall = time.perf_counter() - t0
         FT.kill_point("chunk")
         self._chunk_i += 1
@@ -864,13 +898,14 @@ class MultiTenantRuntime(StreamRuntime):
 
     def _run(self, chunk: eng.EventBatch, start: int):
         start_i = eng.wrap_event_index(start)
-        if self.mesh is not None:
-            from repro.dist import sharding as SH
-            return SH.run_chunk_lanes_sharded(
-                self.cfg, self.model, chunk, self.carry, start_i,
-                mesh=self.mesh)
-        return LN.run_chunk_lanes_donated(self.cfg, self.model, chunk,
-                                          self.carry, start_i)
+        with self.telemetry.span("rt.dispatch"):
+            if self.mesh is not None:
+                from repro.dist import sharding as SH
+                return SH.run_chunk_lanes_sharded(
+                    self.cfg, self.model, chunk, self.carry, start_i,
+                    mesh=self.mesh)
+            return LN.run_chunk_lanes_donated(self.cfg, self.model, chunk,
+                                              self.carry, start_i)
 
     def _group_limit(self) -> int:
         # The sharded path has no grouped runner — chunk-at-a-time.
@@ -882,10 +917,11 @@ class MultiTenantRuntime(StreamRuntime):
         def rs(x):
             x = x.reshape((x.shape[0], g, -1) + x.shape[2:])
             return jnp.swapaxes(x, 0, 1)
-        ev = jax.tree.map(rs, piece)
-        return _run_group_lanes(self.cfg, self.model, ev, self.carry,
-                                eng.wrap_event_index(start),
-                                self.rt.scan_unroll)
+        with self.telemetry.span("rt.dispatch"):
+            ev = jax.tree.map(rs, piece)
+            return _run_group_lanes(self.cfg, self.model, ev, self.carry,
+                                    eng.wrap_event_index(start),
+                                    self.rt.scan_unroll)
 
     def _maybe_refresh(self) -> bool:
         if not self._refresh_on() \
@@ -897,13 +933,14 @@ class MultiTenantRuntime(StreamRuntime):
             m, c, d = RF.refresh_model(
                 self.specs, self.cfg, LN.unstack_lane(self.model, lane),
                 LN.unstack_lane(self.carry, lane), self.rt.refresh,
-                self.refresh_state[lane])
+                self.refresh_state[lane], self.telemetry)
             models.append(m)
             carries.append(c)
             did |= d
         if did:
-            self.model = LN.stack(models)
-            self.carry = LN.stack(carries)
+            with self.telemetry.span("rt.refresh.deploy"):
+                self.model = LN.stack(models)
+                self.carry = LN.stack(carries)
         return did
 
     def merged_carry(self) -> eng.Carry:

@@ -7,13 +7,19 @@ percentiles plus half a dozen scalar reads.  All per-chunk reductions now
 run ON DEVICE in one fused jit (``device_chunk_stats``) and cross to the
 host as a single ~12-float vector per chunk; that transfer doubles as the
 synchronization point the wall-clock measurement needs.  The log
-aggregates into the throughput headline ``benchmarks/bench_runtime.py``
-reports (events/sec, p50/p99 event latency, shed/overflow counters).
+aggregates chunk walls, p50/p99 event latency and shed/overflow counters.
+
+``TelemetryLog.span`` names the host's work inside ``push`` (admission,
+WAL, slicing, dispatch, device wait, stats, match decode, refresh, guard,
+snapshot): each span is a ``jax.profiler.TraceAnnotation``, so a running
+profiler trace shows it beside the device's operations, and its self time
+is charged to a chunk's in-memory ``spans``; ``TelemetryLog.count`` does
+the same for counters (DESIGN.md §7).
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
+import time
 
 import jax
 import jax.numpy as jnp
@@ -86,7 +92,6 @@ class ChunkStats:
     n_events: int               # events processed (all lanes)
     n_lanes: int
     wall_s: float
-    events_per_s: float
     l_e_p50: float
     l_e_p99: float
     l_e_max: float
@@ -102,8 +107,20 @@ class ChunkStats:
     refresh_wall_s: float = 0.0  # host time spent in/gating the refresh
     rung: int = 0               # degradation-ladder rung after this chunk
 
+    def __post_init__(self):
+        # Host seconds of self time by span name, and counters, charged to
+        # this chunk by TelemetryLog.span / count.  Wall readings held in
+        # memory only: not dataclass fields, so never in to_row(), the
+        # snapshot, or a recovery comparison.
+        self.spans: dict[str, float] = {}
+        self.counts: dict[str, int] = {}
+
     def to_row(self) -> dict:
         return dataclasses.asdict(self)
+
+
+# Row keys of snapshots written by earlier builds, no longer fields.
+_RETIRED_ROW_KEYS = ("events_per_s",)
 
 
 @dataclasses.dataclass
@@ -134,7 +151,6 @@ def summarize_chunk(chunk_index: int, start: int, n_events: int,
     return ChunkStats(
         chunk_index=chunk_index, start=start, n_events=n_events,
         n_lanes=n_lanes, wall_s=wall_s,
-        events_per_s=n_events / max(wall_s, 1e-12),
         l_e_p50=v("l_e_p50"), l_e_p99=v("l_e_p99"), l_e_max=v("l_e_max"),
         n_pm_end=v("n_pm_end"),
         shed_events=int(v("shed_events")),
@@ -146,15 +162,112 @@ def summarize_chunk(chunk_index: int, start: int, n_events: int,
     )
 
 
+# The root span: one per ``StreamRuntime.push``.  What closes inside a
+# chunk's work (``begin_chunk`` .. ``extend``) is that chunk's; what closes
+# outside it waits for the root to close and goes to the group the push
+# appended last (or, for a push that appends none, to the next one's).
+PUSH_SPAN = "rt.push"
+
+
+def _add(acc: dict, name: str, v) -> None:
+    acc[name] = acc.get(name, 0) + v
+
+
+class _Span:
+    """One open span: a profiler annotation and a self-time clock."""
+    __slots__ = ("log", "name", "ann", "t0", "child_ns")
+
+    def __init__(self, log: "TelemetryLog", name: str, meta: dict):
+        self.log, self.name = log, name
+        self.ann = jax.profiler.TraceAnnotation(name, **meta)
+
+    def __enter__(self) -> "_Span":
+        self.ann.__enter__()
+        self.child_ns = 0
+        self.log._stack.append(self)
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dur = time.perf_counter_ns() - self.t0
+        stack = self.log._stack
+        stack.pop()
+        if stack:
+            stack[-1].child_ns += dur
+        self.log._charge(self.name, (dur - self.child_ns) * 1e-9, "spans")
+        if not stack:
+            self.log._close_root()
+        self.ann.__exit__(*exc)
+
+
 class TelemetryLog:
-    """Append-only chunk log with run-level aggregation."""
+    """Append-only chunk log with run-level aggregation, and the host
+    spans and counters charged to its chunks."""
 
     def __init__(self):
         self.chunks: list[ChunkStats] = []
         self.events: list[RuntimeEvent] = []
+        self._stack: list[_Span] = []
+        # Totals not yet charged, {"spans": ..., "counts": ...}: those of
+        # the chunk's work under way, and those outside it in this push.
+        self._chunk_held = {"spans": {}, "counts": {}}
+        self._held = {"spans": {}, "counts": {}}
+        self._last: list[ChunkStats] = []   # the group appended last
+        self._running = False               # a chunk's work is under way
+
+    # -- spans and counters ------------------------------------------------
+    def span(self, name: str, **meta) -> _Span:
+        """``with log.span("rt.decode.fetch"):`` -- a profiler annotation
+        of that name (``meta`` rides as its metadata) whose self time (its
+        duration less its children's) is charged under ``name``."""
+        return _Span(self, name, meta)
+
+    def count(self, name: str, n: int) -> None:
+        self._charge(name, int(n), "counts")
+
+    def begin_chunk(self) -> None:
+        """A chunk's (or chunk group's) work starts: what closes from now
+        until ``extend`` is that chunk's."""
+        self._running = True
+
+    def _charge(self, name: str, v, kind: str) -> None:
+        held = self._chunk_held if self._running else self._held
+        _add(held[kind], name, v)
+
+    def _close_root(self) -> None:
+        """The root span closed: the time held outside the chunks goes to
+        the group appended last, if this root appended one."""
+        if self._last:
+            self._release(self._held, self._last)
+            self._last = []
+
+    def _release(self, held: dict, group: list[ChunkStats]) -> None:
+        for kind in ("spans", "counts"):
+            self._put(group, held[kind], kind)
+            held[kind] = {}
+
+    @staticmethod
+    def _put(group: list[ChunkStats], d: dict, kind: str) -> None:
+        """Spans split evenly over a group (as its wall is); counters go
+        whole to its last chunk."""
+        if kind == "counts":
+            for k, v in d.items():
+                _add(group[-1].counts, k, v)
+            return
+        for k, v in d.items():
+            for c in group:
+                _add(c.spans, k, v / len(group))
 
     def append(self, stats: ChunkStats) -> None:
-        self.chunks.append(stats)
+        self.extend([stats])
+
+    def extend(self, group: list[ChunkStats]) -> None:
+        """Append the chunks of one dispatch, charging them what their
+        work left held."""
+        self.chunks.extend(group)
+        self._release(self._chunk_held, group)
+        self._last = list(group)
+        self._running = False
 
     def record_event(self, kind: str, chunk_index: int,
                      detail: dict | None = None) -> RuntimeEvent:
@@ -184,23 +297,28 @@ class TelemetryLog:
         """Rebuild a log from ``to_json`` output (the aggregate is
         recomputed from the rows, never trusted)."""
         log = cls()
-        log.chunks = [ChunkStats(**row) for row in d.get("chunks", [])]
-        log.events = [RuntimeEvent(**row) for row in d.get("events", [])]
+        log.load_json(d)
         return log
+
+    def load_json(self, d: dict) -> None:
+        """Replace the rows with ``to_json`` output, in place (spans and
+        counters still held stay held, for the next chunk appended)."""
+        self.chunks = [ChunkStats(**{k: v for k, v in row.items()
+                                     if k not in _RETIRED_ROW_KEYS})
+                       for row in d.get("chunks", [])]
+        self.events = [RuntimeEvent(**row) for row in d.get("events", [])]
+        self._last = []
 
     def aggregate(self) -> dict:
         if not self.chunks:
-            return {"n_chunks": 0, "n_events": 0, "events_per_s": 0.0}
-        n_events = sum(c.n_events for c in self.chunks)
-        # Aggregate throughput charges the host-side refresh time too —
-        # per-chunk events_per_s is processing-only.
-        wall = sum(c.wall_s + c.refresh_wall_s for c in self.chunks)
+            return {"n_chunks": 0, "n_events": 0}
         return {
             "n_chunks": len(self.chunks),
-            "n_events": n_events,
-            "wall_s": wall,
+            "n_events": sum(c.n_events for c in self.chunks),
+            # chunk walls and refresh: not the push's whole host time
+            # (WAL, snapshots, guard and admission are in the spans)
+            "wall_s": sum(c.wall_s + c.refresh_wall_s for c in self.chunks),
             "refresh_wall_s": sum(c.refresh_wall_s for c in self.chunks),
-            "events_per_s": n_events / max(wall, 1e-12),
             "l_e_p50_max": max(c.l_e_p50 for c in self.chunks),
             "l_e_p99_max": max(c.l_e_p99 for c in self.chunks),
             "l_e_max": max(c.l_e_max for c in self.chunks),

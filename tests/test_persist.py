@@ -89,7 +89,8 @@ class TestSnapshotCodec:
         ctl = {"wal_next_record": 7, "nested": {"a": [1, 2.5, None]}}
         data = PS.build_snapshot_bytes(3, ctl, {"carry": carry,
                                                 "model": model,
-                                                "skipped": None})
+                                                "skipped": None},
+                                       RT.TelemetryLog())
         header, sections = PS.parse_snapshot_bytes(data)
         assert header["chunk_index"] == 3
         assert header["control"] == ctl
@@ -107,7 +108,7 @@ class TestSnapshotCodec:
         cfg = runner.default_config(cp, max_pms=16, **COST)
         carry = eng.init_carry(cfg, seed=0)
         data = PS.build_snapshot_bytes(0, {"wal_next_record": 0},
-                                       {"carry": carry})
+                                       {"carry": carry}, RT.TelemetryLog())
         return cp, carry, data
 
     def test_torn_file_is_corrupt(self, small):
@@ -159,7 +160,8 @@ class TestStoreAndWal:
         cp = pat.compile_patterns([_spec("q1")])
         cfg = runner.default_config(cp, max_pms=16, **COST)
         carry = eng.init_carry(cfg, seed=0)
-        store = PS.SnapshotStore(str(tmp_path), keep_generations=2)
+        store = PS.SnapshotStore(str(tmp_path), RT.TelemetryLog(),
+                                 keep_generations=2)
         for chunk in (1, 2, 3):
             p = store.save(chunk, {"wal_next_record": chunk},
                            {"carry": carry})
@@ -181,16 +183,18 @@ class TestStoreAndWal:
         ev = eng.EventBatch(*[np.arange(4, dtype=np.float32) + i
                               for i in range(len(eng.EventBatch._fields))])
         ev2 = jax.tree.map(lambda x: x * 3, ev)
-        wal = PS.WriteAheadLog(str(tmp_path), fsync_every=2)
+        wal = PS.WriteAheadLog(str(tmp_path), RT.TelemetryLog(),
+                               fsync_every=2)
         assert (wal.append(ev), wal.append(ev2)) == (0, 1)
         wal.close()
         # Reopen resumes ids; a fresh append lands in a NEW segment.
-        wal = PS.WriteAheadLog(str(tmp_path))
+        wal = PS.WriteAheadLog(str(tmp_path), RT.TelemetryLog())
         assert wal.next_record_id == 2
         assert wal.append(ev) == 2
         wal.close()
         assert len(wal.segments()) == 2
-        recs = PS.WriteAheadLog(str(tmp_path)).records_since(1)
+        recs = PS.WriteAheadLog(str(tmp_path),
+                                RT.TelemetryLog()).records_since(1)
         assert [r[0] for r in recs] == [1, 2]
         _assert_tree_equal(ev2, recs[0][1], "record 1")
         _assert_tree_equal(ev, recs[1][1], "record 2")
@@ -198,7 +202,7 @@ class TestStoreAndWal:
     def test_truncated_segment_actionable(self, tmp_path):
         ev = eng.EventBatch(*[np.zeros(3, np.float32)
                               for _ in eng.EventBatch._fields])
-        wal = PS.WriteAheadLog(str(tmp_path))
+        wal = PS.WriteAheadLog(str(tmp_path), RT.TelemetryLog())
         wal.append(ev)
         wal.append(ev)
         seg = wal.segments()[-1][1]
@@ -207,7 +211,7 @@ class TestStoreAndWal:
         with open(seg, "wb") as f:
             f.write(data[:-5])
         with pytest.raises(PS.CorruptSegmentError, match="torn record"):
-            PS.WriteAheadLog(str(tmp_path))
+            PS.WriteAheadLog(str(tmp_path), RT.TelemetryLog())
 
     def test_config_validation(self, tmp_path):
         with pytest.raises(ValueError, match="snapshot_every_chunks"):

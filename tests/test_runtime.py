@@ -304,7 +304,7 @@ class TestRefresh:
                                   eng.init_carry(cfg))
         state = RT.RefreshState()
         m2, carry2, did = RT.refresh_model(specs, cfg, model_w, carry,
-                                           rcfg, state)
+                                           rcfg, state, RT.TelemetryLog())
         assert did and state.refresh_count == 1
         # shapes stable (no chunk retrace), contents updated
         assert m2.ut_tables.shape == model_w.ut_tables.shape
@@ -318,7 +318,8 @@ class TestRefresh:
         rcfg = RT.RefreshConfig(min_observations=1e12)
         state = RT.RefreshState()
         _, _, did = RT.refresh_model(specs, cfg, model,
-                                     eng.init_carry(cfg), rcfg, state)
+                                     eng.init_carry(cfg), rcfg, state,
+                                     RT.TelemetryLog())
         assert not did and state.skipped_obs == 1
 
     def test_drift_gate_skips_stable_stream(self, setup):
@@ -327,8 +328,11 @@ class TestRefresh:
         carry, _ = eng.run_engine(cfg, model, make_events(0),
                                   eng.init_carry(cfg))
         state = RT.RefreshState()
-        _, _, did1 = RT.refresh_model(specs, cfg, model, carry, rcfg, state)
-        _, _, did2 = RT.refresh_model(specs, cfg, model, carry, rcfg, state)
+        log = RT.TelemetryLog()
+        _, _, did1 = RT.refresh_model(specs, cfg, model, carry, rcfg, state,
+                                      log)
+        _, _, did2 = RT.refresh_model(specs, cfg, model, carry, rcfg, state,
+                                      log)
         assert did1 and not did2 and state.skipped_drift == 1
 
     def test_runtime_refreshes_on_cadence(self, setup):
@@ -409,7 +413,7 @@ class TestTelemetry:
         assert [s.n_events for s in stats] == [512, 512, 512, 464]
         assert [s.start for s in stats] == [0, 512, 1024, 1536]
         for s in stats:
-            assert s.events_per_s > 0 and s.l_e_p99 >= s.l_e_p50
+            assert s.n_events / s.wall_s > 0 and s.l_e_p99 >= s.l_e_p50
         agg = srt.telemetry.aggregate()
         assert agg["n_events"] == N_EVENTS
         assert agg["pms_shed"] == pytest.approx(float(srt.carry.pms_shed))
