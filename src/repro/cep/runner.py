@@ -20,7 +20,7 @@ import numpy as np
 
 from repro.cep import engine as eng
 from repro.cep import patterns as pat
-from repro.core import markov, overload as ovl, utility as util
+from repro.core import overload as ovl, utility as util
 from repro.data import streams
 
 Array = jnp.ndarray
@@ -29,9 +29,6 @@ Array = jnp.ndarray
 @dataclasses.dataclass
 class BuiltModel:
     """Everything the model builder produces."""
-    T: list          # per-pattern transition matrices
-    R: list          # per-pattern reward matrices
-    tables: list     # per-pattern UtilityTable
     ut_stacked: Array
     ut_bins: Array
     f_model: ovl.LatencyModel
@@ -71,6 +68,26 @@ def default_config(cp: pat.CompiledPatterns, **kw) -> eng.EngineConfig:
     return eng.EngineConfig(**base)
 
 
+def fit_model(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
+              carry: eng.Carry, *, bin_size: int, width: int | None = None,
+              use_remaining_time: bool = True) -> util.ModelFit:
+    """``utility.fit_model`` of the queries ``specs`` from the observations
+    and latency ring ``carry`` has gathered, ``width`` bins wide (default:
+    the widest query's): one compiled program per query set and shapes,
+    shared by the model build and every refresh."""
+    queries = tuple(util.QueryFit(
+        num_states=s.num_states,
+        num_bins=max(1, -(-s.window_size // bin_size)),
+        weight=float(s.weight),
+        default_reward=cfg.c_match * float(s.proc_cost)) for s in specs)
+    if width is None:
+        width = max(q.num_bins for q in queries)
+    return util.fit_model(carry.obs_counts, carry.obs_rewards,
+                          carry.lat_samples_n, carry.lat_samples_l,
+                          carry.lat_ptr, queries=queries, bin_size=bin_size,
+                          width=width, use_remaining_time=use_remaining_time)
+
+
 def build_model(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
                 warm_events: streams.EventBatch, bin_size: int = 64,
                 use_remaining_time: bool = True,
@@ -86,29 +103,11 @@ def build_model(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
     carry = eng.init_carry(warm_cfg, seed=seed)
     carry, outs = eng.run_engine(warm_cfg, model0, warm_events, carry)
 
-    Ts, Rs, tables = [], [], []
-    for p, spec in enumerate(specs):
-        m = spec.num_states
-        stats = markov.TransitionStats(
-            counts=carry.obs_counts[p, :m, :m],
-            reward_sum=carry.obs_rewards[p, :m, :m])
-        T = markov.estimate_transition_matrix(stats)
-        R = markov.estimate_reward_matrix(
-            stats, default_reward=cfg.c_match * float(spec.proc_cost))
-        Ts.append(T)
-        Rs.append(R)
-        tables.append(util.build_utility_table(
-            T, R, window_size=spec.window_size, bin_size=bin_size,
-            weight=spec.weight, use_remaining_time=use_remaining_time))
-    ut_stacked, ut_bins = util.stack_tables(tables,
-                                            max_states=cp.max_states)
-
-    # Latency regression f from the gathered (n_pm, t_proc) samples.
-    S = carry.lat_samples_n.shape[0]
-    n_valid = jnp.minimum(carry.lat_ptr, S)
-    valid = jnp.arange(S) < n_valid
-    f_model = ovl.fit_latency_model(carry.lat_samples_n,
-                                    carry.lat_samples_l, valid)
+    # Chains, utility tables and the latency regression f from the
+    # gathered observations and (n_pm, t_proc) samples.
+    fit = fit_model(specs, cfg, carry, bin_size=bin_size,
+                    use_remaining_time=use_remaining_time)
+    f_model = fit.f_model
     # g (shed latency) from the simulator's true sort-cost model — in a real
     # deployment these samples come from observed shed calls; the warm run
     # never sheds, so we use the calibrated constants directly.
@@ -121,8 +120,8 @@ def build_model(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
     steady_n_pm = float(np.asarray(outs.n_pm)[-n_tail:].mean())
     t_proc = float(ovl.predict_latency(f_model, jnp.float32(steady_n_pm)))
     max_rate = 1.0 / max(t_proc, 1e-9)
-    return BuiltModel(T=Ts, R=Rs, tables=tables, ut_stacked=ut_stacked,
-                      ut_bins=ut_bins, f_model=f_model, g_model=g_model,
+    return BuiltModel(ut_stacked=fit.ut_tables,
+                      ut_bins=fit.ut_bins, f_model=f_model, g_model=g_model,
                       max_rate=max_rate, steady_n_pm=steady_n_pm)
 
 

@@ -101,6 +101,32 @@ def estimate_reward_matrix(stats: TransitionStats,
                      default_reward)
 
 
+def estimate_chains(counts: Array, reward_sum: Array, num_states,
+                    default_reward) -> tuple[Array, Array]:
+    """``estimate_transition_matrix`` and ``estimate_reward_matrix`` for P
+    queries at once, from (P, M, M) accumulators padded to M states.
+
+    ``num_states`` (P,) and ``default_reward`` (P,) are host constants.
+    Query p's states from ``num_states[p]`` on are padding: no observation
+    there counts, their rows are self-loops and no live state moves to
+    them, so every T is block-diagonal and its live block (absorbing in
+    the query's own final state ``num_states[p] - 1``) evolves alone.
+    """
+    M = counts.shape[-1]
+    m = np.asarray(num_states)[:, None, None]
+    idx = np.arange(M)
+    live = (idx[:, None] < m) & (idx[None, :] < m)          # (P, M, M)
+    c = jnp.where(live, counts, 0.0)
+    row = c.sum(axis=2, keepdims=True)
+    eye = jnp.eye(M, dtype=c.dtype)
+    T = jnp.where(row > 0, c / jnp.maximum(row, 1e-30), eye)
+    T = jnp.where(idx[:, None] == m - 1, eye, T)            # absorbing final
+    dr = np.asarray(default_reward, np.float32)[:, None, None]
+    R = jnp.where(c > 0, jnp.where(live, reward_sum, 0.0)
+                  / jnp.maximum(c, 1e-30), dr)
+    return T, R
+
+
 # ---------------------------------------------------------------------------
 # Completion probability  P_pm = T^{R_w}(i, m)   (paper Eq. 3)
 # ---------------------------------------------------------------------------
@@ -146,6 +172,18 @@ def completion_probability_table(T: Array, num_bins: int,
     return powers[:, :, -1]  # (num_bins, m)
 
 
+def completion_probability_tables(T: Array, num_states, num_bins: int,
+                                  bin_size: int) -> Array:
+    """``completion_probability_table`` for P stacked chains
+    (``estimate_chains``): the column of each query's own final state
+    ``num_states[p] - 1``, from one batched run of the binned powers;
+    (P, num_bins, M)."""
+    powers = jax.vmap(lambda T1: binned_matrix_powers(
+        T1, num_bins, bin_size))(T)
+    final = np.asarray(num_states) - 1
+    return powers[np.arange(T.shape[0]), :, :, final]
+
+
 # ---------------------------------------------------------------------------
 # Remaining processing time via MRP value iteration  (§III-C-2)
 # ---------------------------------------------------------------------------
@@ -166,7 +204,26 @@ def remaining_time_table(T: Array, R: Array, num_bins: int,
     # Expected one-step reward per state: r(s) = sum_s' T[s,s']·R[s,s'].
     r = (T * R).sum(axis=1).at[m - 1].set(0.0)
     T_nofinal = T.at[m - 1].set(0.0)  # absorbing final contributes 0 onward
+    return _value_iteration(r, T_nofinal, num_bins, bin_size)
 
+
+def remaining_time_tables(T: Array, R: Array, num_states, num_bins: int,
+                          bin_size: int) -> Array:
+    """``remaining_time_table`` for P stacked chains (``estimate_chains``):
+    each query's final state is its own ``num_states[p] - 1``, and it and
+    the padding beyond cost nothing onward.  One value iteration over all
+    P queries; (P, num_bins, M)."""
+    M = T.shape[-1]
+    done = np.arange(M)[None, :] >= np.asarray(num_states)[:, None] - 1
+    r = jnp.where(done, 0.0, (T * R).sum(axis=2))
+    T_nofinal = jnp.where(done[:, :, None], 0.0, T)
+    return jax.vmap(lambda r1, T1: _value_iteration(
+        r1, T1, num_bins, bin_size))(r, T_nofinal)
+
+
+def _value_iteration(r: Array, T_nofinal: Array, num_bins: int,
+                     bin_size: int) -> Array:
+    """Every ``bin_size``-th iterate of tau <- r + T_nofinal @ tau."""
     def one_event(tau, _):
         tau = r + T_nofinal @ tau
         return tau, None
@@ -175,7 +232,7 @@ def remaining_time_table(T: Array, R: Array, num_bins: int,
         tau, _ = jax.lax.scan(one_event, tau, None, length=bin_size)
         return tau, tau
 
-    tau0 = jnp.zeros((m,), T.dtype)
+    tau0 = jnp.zeros(r.shape, r.dtype)
     _, taus = jax.lax.scan(one_bin, tau0, None, length=num_bins)
     return taus  # (num_bins, m)
 

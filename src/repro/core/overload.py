@@ -113,6 +113,21 @@ def fit_latency_model(n_pm: Array, latency: Array,
     )
 
 
+def fit_latency_ring(n_pm: Array, latency: Array, ptr: Array
+                     ) -> LatencyModel:
+    """``fit_latency_model`` over a rolling ring of samples whose write
+    pointer ``ptr`` counts every sample ever written.
+
+    On a multi-billion-event stream the int32 pointer wraps negative; by
+    then the ring has long been full, so a wrapped pointer means every
+    slot is valid — without the guard the mask would go all-zero and the
+    fit would degenerate.
+    """
+    S = n_pm.shape[0]
+    n_valid = jnp.where(ptr < 0, S, jnp.minimum(ptr, S))
+    return fit_latency_model(n_pm, latency, jnp.arange(S) < n_valid)
+
+
 def predict_latency(model: LatencyModel, n_pm: Array) -> Array:
     return model.a * _basis(jnp.asarray(n_pm), model.kind) + model.b
 

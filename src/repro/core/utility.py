@@ -9,12 +9,15 @@ only O(1) time complexity").
 from __future__ import annotations
 
 import dataclasses
-from typing import Sequence
+from functools import partial
+from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core import markov
+from repro.analysis.tracing import count_traces
+from repro.core import markov, overload as ovl
 
 Array = jax.Array
 
@@ -23,7 +26,20 @@ _EPS = 1e-6
 
 def _minmax_scale(x: Array, lo: float = _EPS, hi: float = 1.0) -> Array:
     """Scale x into [lo, hi].  Degenerate (constant) tables map to hi."""
-    xmin, xmax = x.min(), x.max()
+    return _scale_between(x, x.min(), x.max(), lo, hi)
+
+
+def _minmax_scale_each(x: Array, where: Array, lo: float = _EPS,
+                       hi: float = 1.0) -> Array:
+    """``_minmax_scale`` of each (P, ...) query over its ``where`` entries."""
+    axes = tuple(range(1, x.ndim))
+    return _scale_between(
+        x, x.min(axes, keepdims=True, where=where, initial=jnp.inf),
+        x.max(axes, keepdims=True, where=where, initial=-jnp.inf), lo, hi)
+
+
+def _scale_between(x: Array, xmin: Array, xmax: Array, lo: float,
+                   hi: float) -> Array:
     span = xmax - xmin
     scaled = jnp.where(span > 0, (x - xmin) / jnp.maximum(span, 1e-30), 1.0)
     return lo + scaled * (hi - lo)
@@ -76,6 +92,68 @@ def build_utility_table(T: Array, R: Array, window_size: int, bin_size: int,
     table = weight * P_s / jnp.maximum(tau_s, _EPS)
     return UtilityTable(table=table, completion=P, remaining=tau,
                         bin_size=bin_size, weight=weight)
+
+
+class QueryFit(NamedTuple):
+    """What ``fit_model`` needs of one query, hashable, so that a
+    deployment's query set compiles the fit once."""
+    num_states: int
+    num_bins: int          # ceil(window_size / bin_size), at least 1
+    weight: float
+    default_reward: float  # R of a transition never observed
+
+
+class ModelFit(NamedTuple):
+    ut_tables: Array          # (P, width, M), 0 beyond each query's bins/states
+    ut_bins: Array            # (P,) int32 bin sizes
+    T: Array                  # (P, M, M) chains, 0 beyond each query's states
+    f_model: ovl.LatencyModel  # f refitted from the latency ring
+    tables_finite: Array      # bool: every entry of ut_tables is finite
+    f_finite: Array           # bool: f's coefficients are finite
+
+
+@partial(jax.jit, static_argnames=("queries", "bin_size", "width",
+                                   "use_remaining_time"))
+@count_traces("utility.fit_model")
+def fit_model(obs_counts: Array, obs_rewards: Array, lat_n: Array,
+              lat_l: Array, lat_ptr: Array, *,
+              queries: tuple[QueryFit, ...], bin_size: int, width: int,
+              use_remaining_time: bool = True) -> ModelFit:
+    """The model builder (paper §III-C/§III-E) for every query at once.
+
+    From the stacked (P, M, M) transition counts and reward sums and the
+    ``(n_pm, latency)`` ring: chains, binned completion probabilities and
+    remaining times out to the widest query's bins, each query's tables
+    min-max scaled over its own bins and states as ``build_utility_table``
+    scales them, stacked and zero-padded as ``stack_tables`` does, then
+    padded or cut to ``width`` bins — plus the latency regression f.  One
+    program, dispatched once.
+    """
+    P, M, _ = obs_counts.shape
+    m = np.array([q.num_states for q in queries])
+    nb = np.array([q.num_bins for q in queries])
+    B = int(nb.max())
+    T, R = markov.estimate_chains(obs_counts, obs_rewards, m,
+                                  [q.default_reward for q in queries])
+    P_tab = markov.completion_probability_tables(T, m, B, bin_size)
+    tau = markov.remaining_time_tables(T, R, m, B, bin_size)
+    valid = ((np.arange(B)[None, :, None] < nb[:, None, None])
+             & (np.arange(M)[None, None, :] < m[:, None, None]))
+    P_s = _minmax_scale_each(P_tab, valid)
+    tau_s = (_minmax_scale_each(tau, valid) if use_remaining_time
+             else jnp.ones_like(tau))
+    w = np.array([q.weight for q in queries], np.float32)[:, None, None]
+    table = jnp.where(valid, w * P_s / jnp.maximum(tau_s, _EPS), 0.0)
+    table = (jnp.pad(table, ((0, 0), (0, width - B), (0, 0)))
+             if width >= B else table[:, :width])
+    f = ovl.fit_latency_ring(lat_n, lat_l, lat_ptr)
+    padding = np.arange(M) >= m[:, None]                      # (P, M)
+    return ModelFit(
+        ut_tables=table, ut_bins=jnp.full((P,), bin_size, jnp.int32),
+        T=jnp.where(padding[:, :, None] | padding[:, None, :], 0.0, T),
+        f_model=f,
+        tables_finite=jnp.isfinite(table).all(),
+        f_finite=jnp.isfinite(f.a) & jnp.isfinite(f.b))
 
 
 def lookup_utility(ut_table: Array, bin_size: int, state: Array,

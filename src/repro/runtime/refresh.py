@@ -19,12 +19,14 @@ from __future__ import annotations
 import dataclasses
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.cep import engine as eng
 from repro.cep import patterns as pat
-from repro.core import markov, overload as ovl, utility as util
+from repro.cep import runner
+from repro.core import overload as ovl
 from repro.runtime import telemetry as TM
 
 
@@ -103,43 +105,12 @@ def prepare_model(specs: Sequence[pat.PatternSpec], model: eng.EngineModel,
                                             mode="edge"))
 
 
-def estimate_chains(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
-                    obs_counts, obs_rewards):
-    """Per-pattern (T, R) from the carry's accumulated observations."""
-    Ts, Rs = [], []
-    for p, spec in enumerate(specs):
-        m = spec.num_states
-        stats = markov.TransitionStats(
-            counts=jnp.asarray(obs_counts[p, :m, :m]),
-            reward_sum=jnp.asarray(obs_rewards[p, :m, :m]))
-        Ts.append(markov.estimate_transition_matrix(stats))
-        Rs.append(markov.estimate_reward_matrix(
-            stats, default_reward=cfg.c_match * float(spec.proc_cost)))
-    return Ts, Rs
-
-
-def _stack_T(Ts, max_states: int) -> np.ndarray:
-    out = np.zeros((len(Ts), max_states, max_states), np.float32)
-    for p, T in enumerate(Ts):
-        m = T.shape[0]
-        out[p, :m, :m] = np.asarray(T)
-    return out
-
-
 def refit_latency_model(carry: eng.Carry) -> ovl.LatencyModel:
-    """Refit f: n_pm -> l_p from the carry's rolling latency ring.
-
-    ``lat_ptr`` increments once per event and, on a multi-billion-event
-    stream, wraps negative (int32); by then the ring has long been full,
-    so a wrapped pointer means every slot is valid — without the guard
-    the mask would go all-zero and the fit would degenerate.
-    """
-    S = carry.lat_samples_n.shape[0]
-    n_valid = jnp.where(carry.lat_ptr < 0, S,
-                        jnp.minimum(carry.lat_ptr, S))
-    valid = jnp.arange(S) < n_valid
-    return ovl.fit_latency_model(carry.lat_samples_n, carry.lat_samples_l,
-                                 valid)
+    """Refit f: n_pm -> l_p from the carry's rolling latency ring
+    (``overload.fit_latency_ring``: a wrapped int32 pointer means a full
+    ring)."""
+    return ovl.fit_latency_ring(carry.lat_samples_n, carry.lat_samples_l,
+                                carry.lat_ptr)
 
 
 def refresh_model(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
@@ -197,45 +168,31 @@ def _gate(carry: eng.Carry, rcfg: RefreshConfig,
 def _fit(specs, cfg, model, carry, rcfg, state):
     """(chains, tables, bins, latency model) fitted from the observations,
     or None where the drift test or a non-finite product skips the
-    refresh."""
-    Ts, Rs = estimate_chains(specs, cfg, carry.obs_counts, carry.obs_rewards)
-    fresh = _stack_T(Ts, cfg.max_states)
+    refresh.  The fit is one compiled program over all queries
+    (``runner.fit_model``, the model build's own); the host reads back the
+    chains and two finiteness flags, once."""
+    # The deployed bin width, so the EngineModel pytree structure (and the
+    # compiled chunk executable) never changes mid-stream.
+    fit = runner.fit_model(specs, cfg, carry, bin_size=rcfg.bin_size,
+                           width=model.ut_tables.shape[1],
+                           use_remaining_time=rcfg.use_remaining_time)
+    fresh, tables_finite, f_finite = jax.device_get(
+        (fit.T, fit.tables_finite, fit.f_finite))
     if rcfg.drift_threshold > 0 and state.last_T is not None:
-        mse = float(max(
-            markov.transition_matrix_mse(jnp.asarray(state.last_T[p]),
-                                         jnp.asarray(fresh[p]))
-            for p in range(len(specs))))
+        mse = float(((state.last_T - fresh) ** 2).mean(axis=(1, 2)).max())
         if mse <= rcfg.drift_threshold:
             state.skipped_drift += 1
             return None
-
-    tables = [util.build_utility_table(
-        T, R, window_size=spec.window_size, bin_size=rcfg.bin_size,
-        weight=spec.weight, use_remaining_time=rcfg.use_remaining_time)
-        for spec, T, R in zip(specs, Ts, Rs)]
-    ut_stacked, ut_bins = util.stack_tables(tables,
-                                            max_states=cfg.max_states)
-    # stack_tables may widen the bin axis vs the deployed model; keep the
-    # deployed width so the EngineModel pytree structure (and the compiled
-    # chunk executable) never changes mid-stream.
-    B = model.ut_tables.shape[1]
-    if ut_stacked.shape[1] < B:
-        ut_stacked = jnp.pad(
-            ut_stacked, ((0, 0), (0, B - ut_stacked.shape[1]), (0, 0)))
-    elif ut_stacked.shape[1] > B:
-        ut_stacked = ut_stacked[:, :B]
     # Same NaN discipline for the freshly built tables and the latency
     # refit: a non-finite product (e.g. an Inf-polluted latency ring that
     # degenerates the regression) keeps the deployed model.
-    if not np.isfinite(np.asarray(ut_stacked)).all():
+    if not tables_finite:
         state.skipped_nonfinite += 1
         return None
     f_model = model.f_model
     if rcfg.refit_latency:
-        cand = refit_latency_model(carry)
-        if bool(np.isfinite(np.asarray(cand.a)).all()
-                and np.isfinite(np.asarray(cand.b)).all()):
-            f_model = cand
+        if f_finite:
+            f_model = fit.f_model
         else:
             state.skipped_nonfinite += 1
-    return fresh, ut_stacked, ut_bins, f_model
+    return fresh, fit.ut_tables, fit.ut_bins, f_model

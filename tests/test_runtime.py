@@ -141,6 +141,39 @@ class TestTenantLanes:
         _assert_tree_equal(cL, mt.carry, "runtime carry")
         assert mt.events_processed == self.L * N_EVENTS
 
+    def test_lane_refresh_equals_per_lane_refresh_model(self, setup,
+                                                        lane_run):
+        """A MultiTenantRuntime refresh is ``refresh_model`` on each lane
+        alone, lanes restacked; a poisoned lane keeps its model."""
+        specs, cfg, _, _ = setup
+        _, _, mL, cL, _ = lane_run
+        cL = cL._replace(obs_counts=cL.obs_counts.at[1, 0, 0, 1].set(
+            jnp.nan))
+        rcfg = RT.RefreshConfig(every_chunks=2, min_observations=1.0)
+        mt = RT.MultiTenantRuntime(cfg, mL, num_lanes=self.L, specs=specs,
+                                   carry=cL,
+                                   rt=RT.RuntimeConfig(chunk_size=512,
+                                                       refresh=rcfg))
+        m0 = mt.model
+        assert mt._maybe_refresh()
+        for lane in range(self.L):
+            state = RT.RefreshState()
+            m, c, did = RT.refresh_model(
+                specs, cfg, RT.unstack_lane(m0, lane),
+                RT.unstack_lane(cL, lane), rcfg, state, RT.TelemetryLog())
+            assert did == (lane != 1)
+            _assert_tree_equal(m, RT.unstack_lane(mt.model, lane),
+                               f"lane {lane} model")
+            _assert_tree_equal(c, RT.unstack_lane(mt.carry, lane),
+                               f"lane {lane} carry")
+            got = mt.refresh_state[lane]
+            assert (got.refresh_count, got.skipped_nonfinite,
+                    got.skipped_obs) == (state.refresh_count,
+                                         state.skipped_nonfinite,
+                                         state.skipped_obs)
+            if did:
+                np.testing.assert_array_equal(got.last_T, state.last_T)
+
 
 class TestLaneSpecs:
     def _cfg(self, p):
