@@ -11,12 +11,17 @@ come out false.
              inert: no class, no window opened)
   altered    an answer altered where it is produced: one decoded match per
              chunk moved to the next event, or, where only counts are
-             output, the first query's completions counted twice
+             output, the first query's completions counted twice (with
+             tenant lanes, in the last lane only, which sits on the last
+             chip of a mesh: one broken tenant among sound ones)
   duplicate  the control: exactly-once broken, the first admitted region
              of the window processed twice, as an at-least-once
              redelivery between admission and the engine would (a
              duplicated push is no control: where the token bucket is
              drained, admission refuses it)
+  lane_swap  the control for tenant isolation (lane runtimes only): the
+             first and the last lane exchange their carries after the
+             window's first dispatch
 
 and, for the readings of the numbers whose limits are not exact:
 
@@ -59,9 +64,12 @@ class _Half:
         return super()._run_region(start, region, n_chunks)
 
 
-def _double_first(old_cc, carry):
+def _double_first(rt, old_cc, carry):
+    """The first query's completions since ``old_cc`` counted twice: in
+    the last lane where ``rt`` has lanes."""
+    at = (rt.num_lanes - 1, 0) if hasattr(rt, "num_lanes") else 0
     cc = carry.complex_count
-    return carry._replace(complex_count=cc.at[0].add(cc[0] - old_cc[0]))
+    return carry._replace(complex_count=cc.at[at].add(cc[at] - old_cc[at]))
 
 
 class _Altered:
@@ -69,7 +77,7 @@ class _Altered:
         if not hasattr(self, "matches"):
             old = jnp.copy(self.carry.complex_count)
             carry, outs = super()._run(chunk, start)
-            return _double_first(old, carry), outs
+            return _double_first(self, old, carry), outs
         before = [set(m) for m in self.matches]
         carry, outs = super()._run(chunk, start)
         for p, m in enumerate(self.matches):
@@ -84,7 +92,7 @@ class _Altered:
     def _run_grouped(self, piece, start, g):
         old = jnp.copy(self.carry.complex_count)
         carry, vecs = super()._run_grouped(piece, start, g)
-        return _double_first(old, carry), vecs
+        return _double_first(self, old, carry), vecs
 
 
 class _Duplicate:
@@ -95,6 +103,17 @@ class _Duplicate:
         again = jax.tree.map(jnp.copy, region)
         out = super()._run_region(start, region, n_chunks)
         return out + super()._run_region(start, again, n_chunks)
+
+
+class _LaneSwap:
+    def _after_chunk(self, out):
+        super()._after_chunk(out)
+        if not getattr(self, "_swapped", False):
+            self._swapped = True
+            ab, ba = jnp.array([0, self.num_lanes - 1]), \
+                jnp.array([self.num_lanes - 1, 0])
+            self.carry = jax.tree.map(lambda x: x.at[ab].set(x[ba]),
+                                      self.carry)
 
 
 def _scale_f(rt, a_by: float, b_to: float | None):
@@ -138,13 +157,16 @@ class _StaleTables:
 
 
 FAULTS = {"stuck": _Stuck, "half": _Half, "altered": _Altered,
-          "duplicate": _Duplicate, "shed_all": _ShedAll,
+          "duplicate": _Duplicate, "lane_swap": _LaneSwap,
+          "shed_all": _ShedAll,
           "shed_none": _ShedNone, "refresh_skipped": _RefreshSkipped,
           "stale_tables": _StaleTables}
 
 
 def plant(runtime, name: str) -> None:
     """Put fault ``name`` in front of ``runtime``'s class, in place."""
+    if name == "lane_swap" and getattr(runtime, "num_lanes", 1) < 2:
+        raise ValueError("lane_swap needs a runtime of two lanes or more")
     mixin = FAULTS[name]
     runtime.__class__ = type(f"{mixin.__name__[1:]}{type(runtime).__name__}",
                              (mixin, type(runtime)), {})

@@ -2,7 +2,7 @@
 """Readings that set the limits of ``correct``: many seeds in one process.
 
     python3 benchmarks/chip/readings.py --workload <cell> --seconds <s> \\
-        --seeds 1,2,3 [--faults none,duplicate,shed_all]
+        --seeds 1,2,3 [--faults none,duplicate,shed_all] [--rehearse]
 
 Runs the cell's whole run (``run.main``) once per fault (``none``: a
 sound run) and seed in this one process, so that set-up compiles once,
@@ -31,6 +31,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--faults", default="none")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="toy sizes on any backend (tests only)")
     args = ap.parse_args(argv)
     for fault, seed in [(f, s) for f in args.faults.split(",")
                         for s in args.seeds.split(",")]:
@@ -38,6 +40,8 @@ def main(argv=None) -> int:
                 str(args.seconds), "--trace", "0"]
         if fault != "none":
             argv += ["--fault", fault]
+        if args.rehearse:
+            argv.append("--rehearse")
         out, err = io.StringIO(), io.StringIO()
         with contextlib.redirect_stdout(out), contextlib.redirect_stderr(err):
             rc = run.main(argv)
@@ -49,10 +53,12 @@ def main(argv=None) -> int:
         last = json.loads(lines[-1])
         print(json.dumps({
             "seed": seed, "fault": fault, "correct": last["correct"],
+            "window": json.loads(lines[0]),
             "attempted": last["attempted"],
             "checks": {k: v["value"] for k, v in last["checks"].items()},
             "info": [json.loads(x) for x in lines[:-1]
-                     if x.startswith('{"reference"')]}), flush=True)
+                     if x.startswith(('{"reference"', '{"lanes"'))]}),
+            flush=True)
     return 0
 
 

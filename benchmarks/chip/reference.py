@@ -38,12 +38,18 @@ reported but not compared):
   latency_x_bound   the largest simulated event latency the program
                     reports in the window, over the configured bound:
                     what Algorithm 1 is for
+
+Tenant lanes are checked one by one (``compare_lanes``): each lane's
+outputs against its own raw stream, the exact numbers summed over lanes,
+the three read numbers the worst lane's.
 """
 from __future__ import annotations
 
 import numpy as np
 
 INF = np.iinfo(np.int64).max // 4
+# The numbers read against limits set from readings (the rest are exact).
+READ = ("lost_per_found", "table_gap", "latency_x_bound")
 
 
 def _q1_pms(q: dict, type_id, attr, E: int):
@@ -298,3 +304,28 @@ def compare(config: dict, raw_cols: tuple, n_offered: int, prog: dict,
                            if k not in config["limits"]})
     return checks, info
 
+
+
+def compare_lanes(configs: list, raws: list, n_offered: int, progs: list,
+                  admissions: list) -> tuple[dict, dict, list]:
+    """``compare`` on each lane alone, against that lane's own raw stream
+    and configuration (``n_offered`` events each: lanes advance in
+    lockstep).  Returns the checks over all lanes (exact numbers summed,
+    the read numbers the worst lane's), the reference's counts summed,
+    and each lane's own counts and checks.  One lane gives ``compare``'s
+    own result."""
+    checks, per_lane = {}, []
+    for config, raw, prog, adm in zip(configs, raws, progs, admissions):
+        c, info = compare(config, raw, n_offered, prog, adm)
+        per_lane.append(dict(info, checks={k: v for k, (v, _) in
+                                           c.items()}))
+        for k, (v, lim) in c.items():
+            if k in checks:
+                old = checks[k][0]
+                v = max(old, v) if k in READ else old + v
+            checks[k] = (v, lim)
+    info = {k: sum(p[k] for p in per_lane)
+            for k in ("opened", "completed", "found")}
+    info["unlimited"] = {k: max(p["unlimited"][k] for p in per_lane)
+                         for k in per_lane[0]["unlimited"]}
+    return checks, info, per_lane

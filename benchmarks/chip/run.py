@@ -16,6 +16,16 @@ JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``,
 ``device``, with ``--trace 1`` a ``breakdown``, and last ``checks``, each
 number compared with its limit.
 
+A configuration of tenant lanes (``served.runtime`` ``"lanes"``; schema
+in ``system.py``) runs the same way on the cell's chips: L lanes, each a
+tenant with its own stream, model and rate, pushed lane-stacked.  Its
+workload's ``push_events`` is per lane, so a push carries L times as many
+events; ``attempted``, ``failed``, ``events_per_s`` and the events the
+per-layer readers divide by count every lane's, an open loop's
+``rate_events_per_s`` is the total over lanes, and the check runs the
+reference on each lane alone.  Lanes emit counts only, so no ``emit_*``
+metric exists for them.
+
 Without a TPU, or with fewer chips than the cell asks for, it exits
 non-zero and prints no result.  ``--rehearse`` runs the cell at toy sizes
 on any backend, for the tests only.  ``--fault`` plants a fault or the
@@ -55,6 +65,22 @@ def load_cell(name: str):
     with open(os.path.join(HERE, "workloads", name + ".json")) as f:
         traffic_mix = json.load(f)
     return bench, cell, config, traffic_mix
+
+
+def lane_cell(bench: dict, cell: dict, config: dict, fault) -> None:
+    """Refuse, before any run, a cell that a lane configuration cannot
+    serve as asked (``system.lane_configs`` refuses the rest)."""
+    import system as S
+    lanes = S.lane_configs(config, cell["chips"])
+    if lanes is None:
+        if fault == "lane_swap":
+            raise ValueError("lane_swap needs a lane configuration")
+        return
+    emit = [m["name"] for m in reported(bench, cell, False)
+            if m["name"].startswith("emit_")]
+    if emit:
+        raise ValueError(f"{emit} listed for a lane cell, whose runtime "
+                         f"emits counts only")
 
 
 def reported(bench: dict, cell: dict, trace: bool) -> list[dict]:
@@ -146,27 +172,6 @@ def _annotate(tracing: bool):
     return jax.profiler.TraceAnnotation
 
 
-def _instrument(rt, ann) -> None:
-    """Host spans around the runtime's layers (traced runs only), so that
-    the trace can say what the host did while the device sat idle."""
-    def wrap(obj, attr, name):
-        fn = getattr(obj, attr)
-
-        def inner(*a, **k):
-            with ann(name):
-                return fn(*a, **k)
-        setattr(obj, attr, inner)
-    for attr, name in (("_run", "rt.chunk"), ("_run_grouped", "rt.group"),
-                       ("_maybe_refresh", "rt.refresh"),
-                       ("_guard_tick", "rt.guard"),
-                       ("_maybe_snapshot", "rt.snapshot")):
-        wrap(rt, attr, name)
-    if rt.persist is not None:
-        wrap(rt.persist.wal, "append", "rt.wal_append")
-    if rt.ingest is not None:
-        wrap(rt.ingest, "offer", "rt.admission")
-
-
 def window(system, mix: dict, lo: int, seconds: float, ann,
            max_pushes: int | None) -> tuple[Record, int, float]:
     """The measured window.  Returns the record, the next stream index
@@ -175,9 +180,12 @@ def window(system, mix: dict, lo: int, seconds: float, ann,
     Closed loop: pushes back to back.  Open loop: the generator creates
     event ``j`` of the window at ``t0 + (j + 1) / rate`` and sends a push
     when its last event exists; a match's latency runs from the creation
-    of its completing event to the end of the push that emitted it."""
+    of its completing event to the end of the push that emitted it.
+    ``lo`` indexes each lane's stream; a push carries ``push_events``
+    events of every lane."""
     import system as S
     rt, push = system.runtime, mix["push_events_scaled"]
+    width = push * (system.lanes or 1)
     rec = Record()
     rate = mix.get("rate_events_per_s")
     timed = rate and system.emits
@@ -187,7 +195,7 @@ def window(system, mix: dict, lo: int, seconds: float, ann,
         t0 = time.perf_counter()
         while max_pushes is None or k < max_pushes:
             if rate:
-                due = t0 + (k + 1) * push / rate
+                due = t0 + (k + 1) * width / rate
                 if due - t0 > seconds:
                     break
             elif time.perf_counter() - t0 >= seconds:
@@ -216,7 +224,7 @@ def window(system, mix: dict, lo: int, seconds: float, ann,
             lo += push
             k += 1
         t_end = time.perf_counter()
-    rec.events = k * push
+    rec.events = k * width
     return rec, lo, t_end - t0
 
 
@@ -235,13 +243,14 @@ def main(argv=None) -> int:
                     help="toy sizes on any backend (tests only)")
     ap.add_argument("--fault", default=None,
                     choices=("stuck", "half", "altered", "duplicate",
-                             "shed_all", "shed_none", "refresh_skipped",
-                             "stale_tables"))
+                             "lane_swap", "shed_all", "shed_none",
+                             "refresh_skipped", "stale_tables"))
     args = ap.parse_args(argv)
     pin_malloc()
 
     try:
         bench, cell, config, mix = load_cell(args.workload)
+        lane_cell(bench, cell, config, args.fault)
     except (OSError, KeyError, ValueError) as e:
         return fail(f"cannot load the cell: {e}")
     if not os.path.isdir(os.path.join(CHECKOUT, "src", "repro")):
@@ -286,8 +295,9 @@ def _run(args, bench, cell, config, mix, devices, clock, out_dir,
     warm_n = mix["warm_pushes"] * push
     max_pushes = mix["rehearsal_pushes"] if args.rehearse else None
     system = S.build(config, seeds, os.path.join(out_dir, "persist"),
-                     args.rehearse)
+                     cell["chips"], args.rehearse)
     rt = system.runtime
+    L = system.lanes or 1
     S.watch_refresh(rt)
     t_built = time.perf_counter() - T_START
     for lo in range(0, warm_n, push):
@@ -299,7 +309,6 @@ def _run(args, bench, cell, config, mix, devices, clock, out_dir,
     ann = _annotate(bool(args.trace))
     trace_dir = os.path.join(out_dir, "trace")
     if args.trace:
-        _instrument(rt, ann)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0       # host spans only, no per-call
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
@@ -322,14 +331,19 @@ def _run(args, bench, cell, config, mix, devices, clock, out_dir,
                       "window_s": wall, "setup_s": setup_s,
                       "setup_marks_s": {"jax_up": t_jax, "built": t_built,
                                         "warm": setup_s},
-                      "max_rate_sim": system.max_rate,
+                      "model_builds_s": system.models_s,
+                      "lanes": system.lanes, "devices": system.devices,
+                      "max_rate_sim": (system.max_rates if system.lanes
+                                       else system.max_rates[0]),
                       "push_ms_median_by_quarter": [
                           float(np.median(x)) * 1e3 if x.size else None
                           for x in quarters]}), flush=True)
 
-    prog = S.final_state(system)
-    prog["l_e_max"] = max((float(c.l_e_max) for c in rec.chunks),
-                          default=0.0)
+    progs = S.final_state(system)
+    # The chunk's latency is the worst lane's.
+    l_e_max = max((float(c.l_e_max) for c in rec.chunks), default=0.0)
+    for prog in progs:
+        prog["l_e_max"] = l_e_max
     fires = int(sum(c.shed_calls for c in rec.chunks))
     bins = int(rt.model.ut_tables.shape[-2])
     system.runtime = rt = None
@@ -351,9 +365,11 @@ def _run(args, bench, cell, config, mix, devices, clock, out_dir,
             line["breakdown"] = rec.trace["breakdown"]
             if rec.trace["kernel_s"] > 0:
                 w = WK.for_config(system.cfg, bins)
+                # Every chip's kernel time against one chip's peak.
                 rec.roofline = WK.roofline(
                     w, rec.events, system.cfg.block_events,
-                    rec.trace["kernel_s"], WK.peaks(used[0].device_kind))
+                    rec.trace["kernel_s"] * rec.trace["devices"],
+                    WK.peaks(used[0].device_kind))
                 print(json.dumps({"roofline": rec.roofline,
                                   "kernel_calls":
                                       rec.trace["kernel_calls"]}),
@@ -373,24 +389,35 @@ def _run(args, bench, cell, config, mix, devices, clock, out_dir,
     print(json.dumps({"matches_in_window": len(rec.latencies),
                       "fires_in_window": fires}), flush=True)
 
-    # The check, outside the window, on the host.
+    # The check, outside the window, on the host: each lane's offered
+    # events and admissions against its own stream.
+    t_ref = time.perf_counter()
     n_offered = lo
-    adm = None
-    if "admission" in config["served"]:
-        rate = system.classifier.rate
-        pushes = [(push, float(np.float32((b + push - 1) / rate)))
-                  for b in range(0, n_offered, push)]
-        a = config["served"]["admission"]
-        adm = REF.admitted(pushes, a["rate_x_max"] * system.max_rate,
-                           a["burst"])
-    raw = system.stream.raw(0, n_offered)
-    checks, info = REF.compare(dict(config, limits=sz["limits"]),
-                               (raw.type_id, raw.attr, raw.group),
-                               n_offered, prog, adm)
-    print(json.dumps({"reference": info, "shed_calls": prog["shed_calls"],
-                      "pms_shed": prog["pms_shed"]}), flush=True)
+    a = config["served"].get("admission")
+    adms, raws = [], []
+    for cl in system.classifiers:
+        adm = None
+        if a is not None:
+            pushes = [(push, float(np.float32((b + push - 1) / cl.rate)))
+                      for b in range(0, n_offered, push)]
+            adm = REF.admitted(pushes, a["rate_x_max"] * max(
+                system.max_rates), a["burst"])
+        adms.append(adm)
+        raw = cl.stream.raw(0, n_offered)
+        raws.append((raw.type_id, raw.attr, raw.group))
+    checks, info, per_lane = REF.compare_lanes(
+        [dict(c, limits=sz["limits"]) for c in system.configs], raws,
+        n_offered, progs, adms)
+    if system.lanes:
+        print(json.dumps({"lanes": per_lane}), flush=True)
+    print(json.dumps({"reference": info,
+                      "shed_calls": sum(p["shed_calls"] for p in progs),
+                      "pms_shed": sum(p["pms_shed"] for p in progs),
+                      "reference_s": time.perf_counter() - t_ref}),
+          flush=True)
     correct = all(v <= lim for v, lim in checks.values())
-    failed = max(0, rec.events - (prog["events_processed"] - warm_n))
+    processed = sum(p["events_processed"] for p in progs)
+    failed = max(0, rec.events - (processed - warm_n * L))
     line = dict({"correct": correct, "attempted": rec.events,
                  "failed": failed, "metrics": metrics,
                  "device": device}, **line)

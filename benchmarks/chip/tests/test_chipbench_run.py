@@ -57,8 +57,8 @@ def test_cell_rehearsal(cell, capsys):
 def test_traced_rehearsal_reports_host_layers(capsys):
     line = _rehearse(capsys, "stock_q1x3.saturate", trace=1)
     assert line["correct"] is True
-    assert {"chunk_wall_ms_per_kev.sat", "refresh_ms_per_kev.sat",
-            "host_other_ms_per_kev.sat"} <= set(line["metrics"])
+    assert {"chunk_wall_ms_per_kev.sat",
+            "refresh_ms_per_kev.sat"} <= set(line["metrics"])
 
 
 # Each fault a cell can have (every cell runs on one chip: there is no

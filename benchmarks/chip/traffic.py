@@ -67,7 +67,9 @@ GENERATORS = {"stock": gen_stock, "soccer": gen_soccer}
 
 class Stream:
     """An unbounded raw stream, generated on demand in blocks of
-    ``BLOCK`` events, block ``b`` from the seed sequence ``(seed, b)``.
+    ``BLOCK`` events, block ``b`` from the seed sequence ``(seed, b)``;
+    a tenant lane's stream has the seed ``(seed, lane)``, so its blocks
+    come from ``(seed, lane, b)``.
 
     Blocks keep the generator's temporaries small: a process that frees
     large host buffers raises glibc's mmap and trim thresholds, and that
@@ -76,18 +78,18 @@ class Stream:
 
     BLOCK = 1 << 18
 
-    def __init__(self, dataset: dict, seed: int):
+    def __init__(self, dataset: dict, seed: int | tuple[int, ...]):
         self.kind = dataset["name"]
         self._gen = GENERATORS[self.kind]
         self._params = {k: v for k, v in dataset.items() if k != "name"}
-        self._seed = seed
+        self._seed = list(seed) if isinstance(seed, tuple) else [seed]
         self._blocks: list = []
         self._carry = -1
         self._rand: list = []
 
     def _grow(self, n: int) -> None:
         while len(self._blocks) * self.BLOCK < n:
-            rng = np.random.default_rng([self._seed, len(self._blocks)])
+            rng = np.random.default_rng(self._seed + [len(self._blocks)])
             cols, self._carry = self._gen(self.BLOCK, rng, self._carry,
                                           **self._params)
             self._blocks.append(cols)
