@@ -68,6 +68,16 @@ def default_config(cp: pat.CompiledPatterns, **kw) -> eng.EngineConfig:
     return eng.EngineConfig(**base)
 
 
+def query_fits(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
+               bin_size: int) -> tuple[util.QueryFit, ...]:
+    """The static per-query arguments of ``utility.fit_model``."""
+    return tuple(util.QueryFit(
+        num_states=s.num_states,
+        num_bins=max(1, -(-s.window_size // bin_size)),
+        weight=float(s.weight),
+        default_reward=cfg.c_match * float(s.proc_cost)) for s in specs)
+
+
 def fit_model(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
               carry: eng.Carry, *, bin_size: int, width: int | None = None,
               use_remaining_time: bool = True) -> util.ModelFit:
@@ -75,11 +85,7 @@ def fit_model(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
     and latency ring ``carry`` has gathered, ``width`` bins wide (default:
     the widest query's): one compiled program per query set and shapes,
     shared by the model build and every refresh."""
-    queries = tuple(util.QueryFit(
-        num_states=s.num_states,
-        num_bins=max(1, -(-s.window_size // bin_size)),
-        weight=float(s.weight),
-        default_reward=cfg.c_match * float(s.proc_cost)) for s in specs)
+    queries = query_fits(specs, cfg, bin_size)
     if width is None:
         width = max(q.num_bins for q in queries)
     return util.fit_model(carry.obs_counts, carry.obs_rewards,

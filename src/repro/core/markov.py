@@ -137,17 +137,25 @@ def binned_matrix_powers(T: Array, num_bins: int, bin_size: int) -> Array:
 
     The paper computes T^{R_w} only at every ``bs`` events to bound memory
     (§III-C-1) and interpolates between bins.  Computed as a scan of m×m
-    matmuls (MXU-friendly).
+    matmuls (MXU-friendly), each at full float32 precision (``_mm``).
     """
     T_bs = _matrix_power(T, bin_size)
 
     def step(acc, _):
-        acc = acc @ T_bs
+        acc = _mm(acc, T_bs)
         return acc, acc
 
     eye = jnp.eye(T.shape[0], dtype=T.dtype)
     _, powers = jax.lax.scan(step, eye, None, length=num_bins)
     return powers  # (num_bins, m, m)
+
+
+def _mm(a: Array, b: Array) -> Array:
+    """``a @ b`` at full float32 precision.  A TPU's default precision
+    rounds float32 operands to bfloat16: a chain's stay probabilities near
+    1 then lose their third digit, and the thousand-step powers and value
+    iterations below drift by tens of percent."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
 def _matrix_power(T: Array, k: int) -> Array:
@@ -156,8 +164,8 @@ def _matrix_power(T: Array, k: int) -> Array:
     base = T
     while k > 0:
         if k & 1:
-            result = result @ base
-        base = base @ base
+            result = _mm(result, base)
+        base = _mm(base, base)
         k >>= 1
     return result
 
@@ -225,7 +233,7 @@ def _value_iteration(r: Array, T_nofinal: Array, num_bins: int,
                      bin_size: int) -> Array:
     """Every ``bin_size``-th iterate of tau <- r + T_nofinal @ tau."""
     def one_event(tau, _):
-        tau = r + T_nofinal @ tau
+        tau = r + _mm(T_nofinal, tau)
         return tau, None
 
     def one_bin(tau, _):

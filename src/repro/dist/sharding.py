@@ -21,7 +21,8 @@ One module owns every PartitionSpec in the system:
       using pm_specs, so multi-query workloads scale past one device.
   lane_specs / run_chunk_lanes_sharded    — the runtime's tenant lanes
       (repro.runtime, DESIGN.md §7): lane axis over "data", per-lane
-      pattern axis over "model", so lanes × patterns cover a 2-D mesh.
+      pattern axis over "model", so lanes × patterns cover a 2-D mesh;
+      lane_refresh_programs places the lanes' model refresh the same way.
 
 Every rule goes through `_fit`, which drops any axis assignment that does
 not divide the dimension — specs are correct by construction on any mesh.
@@ -544,3 +545,40 @@ def run_chunk_lanes_sharded(cfg, model, events, carry, start, mesh=None,
     if fn is None:
         return LN.run_chunk_lanes(cfg, model, events, carry, start)
     return fn(model, events, carry, jnp.asarray(start, jnp.int32)[None])
+
+
+def lane_refresh_programs(fit, deploy, mesh, cfg, num_lanes: int):
+    """The lane refresh's two steps (``runtime.refresh.refresh_lanes``),
+    each one compiled program over every lane of state that ``lane_specs``
+    places on ``mesh``.
+
+    ``fit(obs_counts, obs_rewards, lat_samples_n, lat_samples_l, lat_ptr)
+    -> utility.ModelFit`` of one lane needs all of its patterns, so it is
+    shard_mapped over the lane axis alone: each device fits its own lanes,
+    with no collective unless the pattern axis has to be gathered first.
+    ``deploy(model, (obs_counts, obs_rewards), (ut_tables, ut_bins,
+    f_model), refit, new_f) -> (model, (obs_counts, obs_rewards))`` of one
+    lane is elementwise; its results take ``lane_specs``' shardings, the
+    placement the chunk step gives model and carry.  Nothing is donated.
+    """
+    from repro.core import utility as util
+
+    full = lane_specs(mesh, cfg, num_lanes)
+    whole = lane_specs(mesh, cfg, num_lanes, pattern_axis=None)
+    f = jax.vmap(fit)
+    lane_ax = whole["lane_axis"]
+    if lane_ax is not None:
+        c, m = whole["carry"], whole["model"]
+        f = jax.shard_map(
+            f, mesh=mesh,
+            in_specs=(c.obs_counts, c.obs_rewards, c.lat_samples_n,
+                      c.lat_samples_l, c.lat_ptr),
+            out_specs=util.ModelFit(
+                ut_tables=m.ut_tables, ut_bins=m.ut_bins, T=c.obs_counts,
+                f_model=m.f_model, tables_finite=P(lane_ax),
+                f_finite=P(lane_ax)),
+            check_vma=False)
+    obs = (full["carry"].obs_counts, full["carry"].obs_rewards)
+    d = jax.jit(jax.vmap(deploy),
+                out_shardings=named_tree(mesh, (full["model"], obs)))
+    return jax.jit(f), d

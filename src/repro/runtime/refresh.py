@@ -17,6 +17,7 @@ so stable streams skip the rebuild cost.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Sequence
 
 import jax
@@ -26,7 +27,7 @@ import numpy as np
 from repro.cep import engine as eng
 from repro.cep import patterns as pat
 from repro.cep import runner
-from repro.core import overload as ovl
+from repro.core import overload as ovl, utility as util
 from repro.runtime import telemetry as TM
 
 
@@ -128,17 +129,25 @@ def refresh_model(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
     latency refit) and ``rt.refresh.deploy``.
     """
     with telemetry.span("rt.refresh.obs"):
-        ready = _gate(carry, rcfg, state)
+        ready = _gate(*jax.device_get((carry.obs_counts, carry.obs_rewards)),
+                      rcfg, state)
     if not ready:
         return model, carry, False
     with telemetry.span("rt.refresh.fit"):
-        fit = _fit(specs, cfg, model, carry, rcfg, state)
-    if fit is None:
+        # The deployed bin width, so the EngineModel pytree structure (and
+        # the compiled chunk executable) never changes mid-stream.
+        fit = runner.fit_model(specs, cfg, carry, bin_size=rcfg.bin_size,
+                               width=model.ut_tables.shape[1],
+                               use_remaining_time=rcfg.use_remaining_time)
+        fresh, tables_finite, f_finite = jax.device_get(
+            (fit.T, fit.tables_finite, fit.f_finite))
+        new_f = _decide(fresh, tables_finite, f_finite, rcfg, state)
+    if new_f is None:
         return model, carry, False
-    fresh, ut_stacked, ut_bins, f_model = fit
     with telemetry.span("rt.refresh.deploy"):
-        model = model._replace(ut_tables=ut_stacked, ut_bins=ut_bins,
-                               f_model=f_model)
+        model = model._replace(
+            ut_tables=fit.ut_tables, ut_bins=fit.ut_bins,
+            f_model=fit.f_model if new_f else model.f_model)
         if rcfg.decay < 1.0:
             carry = carry._replace(obs_counts=carry.obs_counts * rcfg.decay,
                                    obs_rewards=carry.obs_rewards * rcfg.decay)
@@ -147,15 +156,96 @@ def refresh_model(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
     return model, carry, True
 
 
-def _gate(carry: eng.Carry, rcfg: RefreshConfig,
+def refresh_lanes(specs: Sequence[pat.PatternSpec], cfg: eng.EngineConfig,
+                  model: eng.EngineModel, carry: eng.Carry,
+                  rcfg: RefreshConfig, states: Sequence[RefreshState],
+                  telemetry: TM.TelemetryLog, mesh=None,
+                  ) -> tuple[eng.EngineModel, eng.Carry, int]:
+    """``refresh_model`` on every lane of lane-stacked ``model`` and
+    ``carry`` at once; returns ``(model, carry, lanes refit)``.
+
+    Each lane is gated, fitted and deployed as ``refresh_model`` would do
+    it alone (``states[l]`` is lane ``l``'s), but the lanes share each
+    step: one device→host read of every lane's observations for the gates
+    (``rt.refresh.obs``), one compiled fit of all lanes and one read-back
+    of their chains and finiteness flags for the host's decisions
+    (``rt.refresh.fit``), and one compiled deploy that takes the fit where
+    a lane's decision says so and keeps the deployed model elsewhere
+    (``rt.refresh.deploy``).  On ``mesh`` both programs are placed as
+    ``dist.sharding.lane_refresh_programs`` places them: each device fits
+    its own lanes, and model and carry keep the chunk step's sharding.
+    Nothing is donated.  Counters: ``lanes_refit`` and ``lanes_skipped``.
+    """
+    L = len(states)
+    refit = np.zeros(L, bool)
+    new_f = np.zeros(L, bool)
+    with telemetry.span("rt.refresh.obs"):
+        obs_c, obs_r = jax.device_get((carry.obs_counts, carry.obs_rewards))
+        ready = [_gate(obs_c[l], obs_r[l], rcfg, st)
+                 for l, st in enumerate(states)]
+    if any(ready):
+        fit_p, deploy_p = _lane_programs(
+            runner.query_fits(specs, cfg, rcfg.bin_size), rcfg.bin_size,
+            model.ut_tables.shape[-2], rcfg.use_remaining_time, rcfg.decay,
+            cfg, mesh, L)
+        with telemetry.span("rt.refresh.fit"):
+            fit = fit_p(carry.obs_counts, carry.obs_rewards,
+                        carry.lat_samples_n, carry.lat_samples_l,
+                        carry.lat_ptr)
+            fresh, tables_finite, f_finite = jax.device_get(
+                (fit.T, fit.tables_finite, fit.f_finite))
+            for l in np.flatnonzero(ready):
+                d = _decide(fresh[l], tables_finite[l], f_finite[l], rcfg,
+                            states[l])
+                refit[l], new_f[l] = d is not None, bool(d)
+    if refit.any():
+        with telemetry.span("rt.refresh.deploy"):
+            model, obs = deploy_p(
+                model, (carry.obs_counts, carry.obs_rewards),
+                (fit.ut_tables, fit.ut_bins, fit.f_model), refit, new_f)
+            if rcfg.decay < 1.0:
+                carry = carry._replace(obs_counts=obs[0], obs_rewards=obs[1])
+        for l in np.flatnonzero(refit):
+            states[l].last_T = fresh[l]
+            states[l].refresh_count += 1
+    n = int(refit.sum())
+    telemetry.count("lanes_refit", n)
+    telemetry.count("lanes_skipped", L - n)
+    return model, carry, n
+
+
+@functools.lru_cache(maxsize=16)
+def _lane_programs(queries: tuple, bin_size: int, width: int,
+                   use_remaining_time: bool, decay: float,
+                   cfg: eng.EngineConfig, mesh, num_lanes: int):
+    """The lane refresh's fit and deploy, each one program over the lanes,
+    built once per deployment."""
+    fit = functools.partial(util.fit_model, queries=queries,
+                            bin_size=bin_size, width=width,
+                            use_remaining_time=use_remaining_time)
+
+    def deploy(model, obs, new, refit, new_f):
+        ut, bins, f = new
+        model = model._replace(
+            ut_tables=jnp.where(refit, ut, model.ut_tables),
+            ut_bins=jnp.where(refit, bins, model.ut_bins),
+            f_model=jax.tree.map(lambda a, b: jnp.where(new_f, a, b), f,
+                                 model.f_model))
+        return model, tuple(jnp.where(refit, x * decay, x) for x in obs)
+
+    if mesh is None:
+        return jax.jit(jax.vmap(fit)), jax.jit(jax.vmap(deploy))
+    from repro.dist import sharding as SH
+    return SH.lane_refresh_programs(fit, deploy, mesh, cfg, num_lanes)
+
+
+def _gate(obs_c: np.ndarray, obs_r: np.ndarray, rcfg: RefreshConfig,
           state: RefreshState) -> bool:
-    """The carry's observations read on the host: False where they are
+    """The observations, read on the host: False where they are
     non-finite or too few, and the refresh is skipped."""
     # NaN-safe gate (DESIGN.md §12): a poisoned accumulator must SKIP the
     # refresh, not deploy corrupt tables.  Note `nan < threshold` is False
     # — the min-observation gate alone would wave NaNs straight through.
-    obs_c = np.asarray(carry.obs_counts)
-    obs_r = np.asarray(carry.obs_rewards)
     if not (np.isfinite(obs_c).all() and np.isfinite(obs_r).all()):
         state.skipped_nonfinite += 1
         return False
@@ -165,19 +255,13 @@ def _gate(carry: eng.Carry, rcfg: RefreshConfig,
     return True
 
 
-def _fit(specs, cfg, model, carry, rcfg, state):
-    """(chains, tables, bins, latency model) fitted from the observations,
-    or None where the drift test or a non-finite product skips the
-    refresh.  The fit is one compiled program over all queries
-    (``runner.fit_model``, the model build's own); the host reads back the
-    chains and two finiteness flags, once."""
-    # The deployed bin width, so the EngineModel pytree structure (and the
-    # compiled chunk executable) never changes mid-stream.
-    fit = runner.fit_model(specs, cfg, carry, bin_size=rcfg.bin_size,
-                           width=model.ut_tables.shape[1],
-                           use_remaining_time=rcfg.use_remaining_time)
-    fresh, tables_finite, f_finite = jax.device_get(
-        (fit.T, fit.tables_finite, fit.f_finite))
+def _decide(fresh: np.ndarray, tables_finite, f_finite, rcfg: RefreshConfig,
+            state: RefreshState) -> bool | None:
+    """The host's decision on a fit (``utility.fit_model``: one compiled
+    program over all queries) from its chains and two finiteness flags:
+    None where the drift test or non-finite tables skip the refresh, else
+    whether the refitted latency model ``f`` is deployed with the
+    tables."""
     if rcfg.drift_threshold > 0 and state.last_T is not None:
         mse = float(((state.last_T - fresh) ** 2).mean(axis=(1, 2)).max())
         if mse <= rcfg.drift_threshold:
@@ -189,10 +273,8 @@ def _fit(specs, cfg, model, carry, rcfg, state):
     if not tables_finite:
         state.skipped_nonfinite += 1
         return None
-    f_model = model.f_model
-    if rcfg.refit_latency:
-        if f_finite:
-            f_model = fit.f_model
-        else:
-            state.skipped_nonfinite += 1
-    return fresh, fit.ut_tables, fit.ut_bins, f_model
+    if not rcfg.refit_latency:
+        return False
+    if not f_finite:
+        state.skipped_nonfinite += 1
+    return bool(f_finite)

@@ -837,9 +837,12 @@ class MultiTenantRuntime(StreamRuntime):
     leading ``(L,)`` axis (``lanes.stack``) — and lanes advance in lockstep
     over aligned chunk windows.  Models may be shared
     (``lanes.broadcast_model``) or per-lane; refresh runs PER LANE from
-    each lane's own carry, so tenants adapt to their own stream's drift.
-    On a multi-device mesh, pass ``mesh`` to spread lanes × patterns via
-    ``repro.dist.sharding.run_chunk_lanes_sharded``.
+    each lane's own carry, so tenants adapt to their own stream's drift,
+    as one program over the lanes (``refresh.refresh_lanes``).  On a
+    multi-device mesh, pass ``mesh`` to spread lanes × patterns via
+    ``repro.dist.sharding.run_chunk_lanes_sharded``: model and carry are
+    placed on the mesh once, and the chunk step and the refresh keep them
+    there.
     """
 
     def __init__(self, cfg: eng.EngineConfig, model: eng.EngineModel,
@@ -855,6 +858,13 @@ class MultiTenantRuntime(StreamRuntime):
         # chunk over the EVENT axis (axis 1 of lane-stacked leaves)
         self._buf = chunker.ChunkBuffer(self.rt.chunk_size, axis=1)
         self.refresh_state = [RF.RefreshState() for _ in range(num_lanes)]
+        if mesh is not None:
+            from repro.dist import sharding as SH
+            sp = SH.lane_specs(mesh, cfg, num_lanes)
+            self.model = jax.device_put(self.model,
+                                        SH.named_tree(mesh, sp["model"]))
+            self.carry = jax.device_put(self.carry,
+                                        SH.named_tree(mesh, sp["carry"]))
 
     def _make_ingest(self):
         # One bounded queue PER TENANT LANE, re-aligned into lockstep
@@ -928,20 +938,10 @@ class MultiTenantRuntime(StreamRuntime):
            or self._chunk_i % self.rt.refresh.every_chunks != 0:
             return False
         FT.kill_point("refresh")
-        models, carries, did = [], [], False
-        for lane in range(self.num_lanes):
-            m, c, d = RF.refresh_model(
-                self.specs, self.cfg, LN.unstack_lane(self.model, lane),
-                LN.unstack_lane(self.carry, lane), self.rt.refresh,
-                self.refresh_state[lane], self.telemetry)
-            models.append(m)
-            carries.append(c)
-            did |= d
-        if did:
-            with self.telemetry.span("rt.refresh.deploy"):
-                self.model = LN.stack(models)
-                self.carry = LN.stack(carries)
-        return did
+        self.model, self.carry, n = RF.refresh_lanes(
+            self.specs, self.cfg, self.model, self.carry, self.rt.refresh,
+            self.refresh_state, self.telemetry, mesh=self.mesh)
+        return n > 0
 
     def merged_carry(self) -> eng.Carry:
         """All lanes folded into one L·P-pattern carry (engine.merge_carries)

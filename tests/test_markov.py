@@ -219,3 +219,36 @@ class TestOverloadDetector:
         dec = overload.detect_overload(f, f, jnp.float32(0.0),
                                        jnp.int32(10), 1.0)
         assert not bool(dec.shed) and int(dec.rho) == 0
+
+
+def _dot_precisions(jaxpr):
+    """The ``precision`` of every dot_general in ``jaxpr`` and the
+    programs it calls (scan bodies, nested jits)."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                out += _dot_precisions(sub)
+    return out
+
+
+def test_fit_matmuls_run_at_full_float32_precision():
+    """A TPU's default precision rounds float32 matmul operands to
+    bfloat16: a stay probability of 0.99 then keeps two digits, and the
+    fit's thousand-step powers and value iterations come out tens of
+    percent off.  Every matmul of the fit asks for full precision."""
+    m = 4
+    c = jnp.asarray(np.diag([500.0, 300.0, 200.0, 0.0])
+                    + np.diag([5.0, 4.0, 3.0], 1), jnp.float32)[None]
+    q = (utility.QueryFit(num_states=m, num_bins=3, weight=1.0,
+                          default_reward=6e-5),)
+    jaxpr = jax.make_jaxpr(lambda c, r: utility.fit_model(
+        c, r, jnp.zeros(8), jnp.zeros(8), jnp.int32(0), queries=q,
+        bin_size=16, width=3))(c, c * 6e-5)
+    found = _dot_precisions(jaxpr.jaxpr)
+    highest = jax.lax.Precision.HIGHEST
+    assert len(found) >= 3
+    assert all(p in (highest, (highest, highest)) for p in found), found

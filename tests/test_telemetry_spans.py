@@ -253,3 +253,34 @@ def test_span_overhead_without_a_trace():
     with log.span(TM.PUSH_SPAN):
         log.append(TM.ChunkStats(**ROW))
     assert log.chunks[1].spans["rt.stats"] > 0
+
+
+@pytest.mark.parametrize("group_chunks", [1, 4])
+def test_lane_refresh_spans_once_for_all_lanes(group_chunks):
+    """One due refresh over L tenant lanes opens each ``rt.refresh.*``
+    span once, not once a lane, and counts every lane as refit or
+    skipped (``lanes_refit``, ``lanes_skipped``) on the chunk it ends."""
+    specs, cfg, model, ev = SV.build_workload(SPEC)
+    L, n = 4, 256
+    evL = RT.stack([RT.slice_events(ev, lane * n, (lane + 1) * n)
+                    for lane in range(L)])
+    # The last lane opens no window: no PM, no observation to fit.
+    evL = evL._replace(ev_open=evL.ev_open.at[L - 1].set(False))
+    mt = RT.MultiTenantRuntime(
+        cfg, RT.broadcast_model(model, L), num_lanes=L, specs=specs,
+        rt=RT.RuntimeConfig(chunk_size=64, group_chunks=group_chunks,
+                            refresh=RT.RefreshConfig(
+                                every_chunks=4, min_observations=4.0)))
+    opened, span = [], mt.telemetry.span
+
+    def recording(name, **meta):
+        opened.append(name)
+        return span(name, **meta)
+    mt.telemetry.span = recording
+    stats = mt.push(evL)
+    assert len(stats) == 4 and stats[-1].refreshed
+    assert sorted(k for k in opened if k.startswith("rt.refresh.")) == [
+        "rt.refresh.deploy", "rt.refresh.fit", "rt.refresh.obs"]
+    assert stats[-1].counts == {"lanes_refit": L - 1, "lanes_skipped": 1}
+    assert all(not c.counts for c in stats[:-1])
+    assert [st.skipped_obs for st in mt.refresh_state] == [0] * (L - 1) + [1]
